@@ -1,7 +1,8 @@
 """Beyond-paper: the assignment kernel family (CGSim assignJob == MoE router,
-DESIGN.md §3) — jnp oracle vs Pallas(interpret) on simulator- and
-router-shaped problems.  On CPU the interpret-mode kernel measures semantics,
-not speed; the oracle timing is the deployable-jnp datapoint."""
+DESIGN.md §3) — jnp oracle vs the Pallas kernel on simulator- and
+router-shaped problems.  The full configuration runs the compiled kernel and
+needs a TPU; ``--tiny`` runs the kernel in the Pallas interpreter, which
+measures semantics, not speed."""
 from __future__ import annotations
 
 import sys
@@ -37,8 +38,8 @@ def main():
         f_ref = jax.jit(lambda s: assign_ref(s, sizes, caps, k=k))
         t_ref = timed(f_ref, scores)
         print(csv_row(f"assign_ref_{name}", t_ref * 1e6, f"N={N};E={E};k={k}"))
-        # interpret-mode correctness spot check vs oracle on this shape
-        out_k = assign(scores, sizes, caps, k=k, use_kernel=True)
+        # kernel correctness spot check vs oracle on this shape
+        out_k = assign(scores, sizes, caps, k=k, use_kernel=True, interpret=tiny)
         out_r = assign(scores, sizes, caps, k=k, use_kernel=False)
         ok = all(
             np.allclose(np.asarray(a), np.asarray(b), atol=1e-5)
@@ -61,7 +62,8 @@ def main():
         for tag, flag in (("backend_default", None), ("forced_kernel", True)):
             pol = with_capacity_assign(
                 get_policy("panda_dispatch"),
-                make_capacity_assign(jobs_cores=jobs.cores, use_kernel=flag),
+                make_capacity_assign(jobs_cores=jobs.cores, use_kernel=flag,
+                                     interpret=bool(flag)),
             )
             t0 = time.perf_counter()
             res = simulate(jobs, sites, pol, jax.random.PRNGKey(0))
@@ -91,7 +93,8 @@ def main():
         for tag, flag in (("oracle", False), ("interpret_kernel", True)):
             pol = with_fused_assign(
                 get_policy("panda_dispatch"),
-                make_fused_capacity_assign(jobs_cores=jobs.cores, use_kernel=flag),
+                make_fused_capacity_assign(jobs_cores=jobs.cores, use_kernel=flag,
+                                           interpret=flag),
             )
             t0 = time.perf_counter()
             res = simulate(jobs, sites, pol, jax.random.PRNGKey(0),

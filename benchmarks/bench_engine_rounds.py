@@ -17,8 +17,9 @@ Numbers the perf trajectory tracks across commits:
   global-max pad, trading a handful of compiles for fewer wasted dense rows
   (DESIGN.md §8).
 - ``ensemble_steady_*`` and ``ensemble_sharded_*``: the warm-cache steady
-  state, measured in a subprocess whose host platform is forced to
-  ``--devices`` (default 4) CPU devices.  ``ensemble_steady_many_16`` runs
+  state, measured over the accelerator's devices in this process, or on CPU
+  in a subprocess whose host platform is forced to ``--devices`` (default 4)
+  devices.  ``ensemble_steady_many_16`` runs
   the ensemble through ``simulate_many_sharded`` on the full mesh — each
   device retires its own lane block in its own while_loop (no global
   lock-step) — and its ratio against the solo-``simulate`` loop *measured in
@@ -98,8 +99,8 @@ def _ragged_ensemble(tiny: bool):
 
 
 def _ensemble_worker(tiny: bool) -> None:
-    """Runs in a subprocess whose host platform is forced to N devices: the
-    steady-state (warm jit cache) ensemble rows, all measured in this one
+    """Runs over every device of this process (on CPU, a subprocess whose
+    host platform is forced to N devices): the steady-state (warm jit cache) ensemble rows, all measured in this one
     fixed environment so loop / vmap / sharded compare apples-to-apples.
 
     - ``ensemble_sharded_{d}dev`` rows share the *same* flat stacked input
@@ -279,24 +280,30 @@ def main():
         f"speedup_vs_loop=x{t_loop / t_buck:.2f}",
     ))
 
-    # --- steady state + shard scaling, on an N-device host (subprocess: the
-    # host platform device count must be fixed before jax initializes) ------
-    env = dict(os.environ)
-    flags = [f for f in env.get("XLA_FLAGS", "").split() if "host_platform_device_count" not in f]
-    env["XLA_FLAGS"] = " ".join(flags + [f"--xla_force_host_platform_device_count={n_dev}"])
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, "-m", "benchmarks.bench_engine_rounds", "--ensemble-worker"]
-    if tiny:
-        cmd.append("--tiny")
-    out = subprocess.run(
-        cmd, env=env, capture_output=True, text=True, timeout=1800,
-        cwd=os.path.dirname(src),
-    )
-    if out.returncode != 0:
-        print(f"# ensemble worker FAILED (devices={n_dev}):")
-        sys.stdout.write(out.stderr[-2000:] + "\n")
+    # --- steady state + shard scaling ------------------------------------
+    # on an accelerator, in this process over its own devices (a chip serves
+    # one process); on CPU, in a child whose host platform is forced to
+    # ``n_dev`` devices (the count must be fixed before jax initializes)
+    if jax.default_backend() != "cpu":
+        _ensemble_worker(tiny)
     else:
+        env = dict(os.environ)
+        flags = [f for f in env.get("XLA_FLAGS", "").split()
+                 if "host_platform_device_count" not in f]
+        env["XLA_FLAGS"] = " ".join(
+            flags + [f"--xla_force_host_platform_device_count={n_dev}"])
+        src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
+        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        cmd = [sys.executable, "-m", "benchmarks.bench_engine_rounds", "--ensemble-worker"]
+        if tiny:
+            cmd.append("--tiny")
+        out = subprocess.run(
+            cmd, env=env, capture_output=True, text=True, timeout=1800,
+            cwd=os.path.dirname(src),
+        )
+        if out.returncode != 0:
+            raise RuntimeError(
+                f"ensemble worker failed (devices={n_dev}):\n{out.stderr[-2000:]}")
         sys.stdout.write(out.stdout)
 
     # --- single-run round throughput -------------------------------------

@@ -104,6 +104,9 @@ def write_json(name: str, fn, out_dir: pathlib.Path, manifest=None) -> list[str]
 
 
 def main() -> None:
+    from repro.core.telemetry import enable_compile_cache
+
+    enable_compile_cache()
     args = [a for a in sys.argv[1:]]
     as_json = "--json" in args
     out_dir = pathlib.Path(".")

@@ -22,7 +22,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .engine import (
     Scenario,
@@ -36,11 +36,17 @@ from .engine import (
 from .types import JobsState, SimResult, SiteState
 
 
-def use_mesh(mesh: Mesh):
-    """Mesh-context compat: ``jax.set_mesh`` (new API) or the Mesh object
-    itself, which is a context manager on older jax (<= 0.4.x)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis ``Auto``.
+
+    ``jax.make_mesh`` builds ``Explicit`` axes, under which every op must
+    agree on its operands' shardings: the engine's start-order sort over a
+    sharded job column and a replicated tiebreak, or a gather of replicated
+    site rows by sharded job indices, then fails to trace.  Under ``Auto``
+    axes XLA's partitioner chooses those shardings, so the engine runs with
+    no mesh-specific code.  Every entry point below takes its mesh through
+    here."""
+    return Mesh(mesh.devices, mesh.axis_names, axis_types=(AxisType.Auto,) * mesh.devices.ndim)
 
 
 def job_shardings(mesh: Mesh, axis: str, jobs: JobsState, sites: SiteState):
@@ -111,9 +117,10 @@ def simulate_distributed(
 ) -> SimResult:
     """Job-parallel simulation: identical semantics to ``engine.simulate``
     (same event rounds, same FIFO), with XLA SPMD distributing each round."""
+    mesh = auto_mesh(mesh)
     jobs_d, sites_d = shard_jobs(jobs, sites, mesh, axis)
     kw = _prepare_subsystems(kw, jobs_d, sites_d, mesh, jobs.capacity)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return simulate(jobs_d, sites_d, policy, rng, **kw)
 
 
@@ -128,6 +135,7 @@ def lower_distributed(
 ):
     """Lower+compile the engine for a mesh from ShapeDtypeStructs only —
     the simulator's own multi-pod dry-run (no allocation)."""
+    mesh = auto_mesh(mesh)
     jsh, ssh, rsh = job_shardings(mesh, axis, jobs, sites)
     jobs_s = jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), jobs, jsh)
     sites_s = jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), sites, ssh)
@@ -136,7 +144,7 @@ def lower_distributed(
     def fn(j, s, r):
         return simulate(j, s, policy, r, **kw)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn).lower(jobs_s, sites_s, rng_s)
         return lowered, lowered.compile()
 
@@ -154,6 +162,7 @@ def simulate_ensemble_distributed(
 ) -> SimResult:
     """K independent sims (calibration ensemble), candidates sharded over the
     mesh axis — embarrassingly parallel, zero collectives in steady state."""
+    mesh = auto_mesh(mesh)
     K = speed_candidates.shape[0]
     n_dev = mesh.shape[axis]
     if K % n_dev:
@@ -165,27 +174,13 @@ def simulate_ensemble_distributed(
     def one(speed, key):
         return simulate(jobs, sites._replace(speed=speed), policy, key, **kw)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.vmap(one)(cand, keys)
 
 
 # --------------------------------------------------------------------------
 # sharded scenario ensembles: lock-step-free simulate_many (DESIGN.md §8)
 # --------------------------------------------------------------------------
-
-
-def _shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions (experimental on <= 0.4.x)."""
-    try:
-        from jax import shard_map as _sm  # jax >= 0.5-ish
-
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
 
 
 @functools.lru_cache(maxsize=None)
@@ -218,10 +213,11 @@ def _sharded_ensemble_fn(policy, subsystems, mesh, axis, donate, lane_mode, kw_i
             return res
         return jax.vmap(one)(jobs, sites, ext, keys)
 
-    fn = _shard_map_compat(
-        block, mesh,
+    fn = jax.shard_map(
+        block, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis)),
         out_specs=P(axis),
+        check_vma=False,
     )
     # the stacked lane buffers are device_put copies owned by the caller
     # below, so they are donated into the program: XLA aliases them straight
@@ -272,7 +268,8 @@ def _sharded_stacked(
         # untouched — donating would hand the *caller's* buffers to XLA and
         # invalidate them for the next call, so fall back to non-donating
         leaves = jax.tree.leaves((scenarios, keys))
-        if any(getattr(x, "sharding", None) == sh for x in leaves):
+        if any(isinstance(x, jax.Array) and x.sharding.is_equivalent_to(sh, x.ndim)
+               for x in leaves):
             donate = False
     args = jax.tree.map(
         lambda x: jax.device_put(jnp.asarray(x), sh),
@@ -282,7 +279,7 @@ def _sharded_stacked(
         policy, tuple(subsystems), mesh, axis, donate, lane_mode,
         tuple(sorted(kw.items())),
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         res = fn(*args)
     if pad:
         res = jax.tree.map(lambda x: x[:K], res)
@@ -331,6 +328,7 @@ def simulate_many_sharded(
     and (for bucketed input) the measured padding-waste breakdown from
     ``ScenarioBuckets.padding_stats`` — the numbers behind the PR 5 win.
     """
+    mesh = auto_mesh(mesh)
     runner = lambda scen, keys: _sharded_stacked(  # noqa: E731
         scen, keys, policy, mesh, axis, subsystems, donate, lane_mode, kw
     )

@@ -279,16 +279,36 @@ def _start_order_packed_batched(axis_size, in_batched, packed):
     p = packed if in_batched[0] else jnp.broadcast_to(packed, (K,) + packed.shape)
     J = p.shape[-1]
     lane = jnp.broadcast_to(jnp.arange(K, dtype=jnp.int32)[:, None], (K, J)).reshape(-1)
-    perm = jnp.lexsort((p.reshape(-1), lane))
+    perm = _lexsort_i32((p.reshape(-1), lane))
     order = perm.reshape(K, J).astype(jnp.int32) - (jnp.arange(K, dtype=jnp.int32) * J)[:, None]
     return order, True
+
+
+def _f32_sort_key(x: jax.Array) -> jax.Array:
+    """Order-preserving ``i32`` image of an ``f32`` array under ``lax.sort``'s
+    float order (-0 equals +0, NaN sorts last)."""
+    x = jnp.where(x == 0, jnp.float32(0), x.astype(jnp.float32))
+    x = jnp.where(jnp.isnan(x), jnp.float32(jnp.nan), x)
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def _lexsort_i32(keys) -> jax.Array:
+    """``jnp.lexsort`` over ``i32`` keys (last key most significant, ties by
+    index) as one stable single-key argsort per key: the same permutation.
+    The TPU compiler takes minutes over a multi-key or float sort at
+    J=100k and seconds over these."""
+    perm = jnp.argsort(keys[0], stable=True)
+    for key in keys[1:]:
+        perm = perm[jnp.argsort(key[perm], stable=True)]
+    return perm
 
 
 def _static_start_rank(jobs) -> jax.Array:
     """``i32[J]``: rank of each job under ``(-priority, arrival, index)`` —
     the run-constant suffix of the start-order key (see ``_start_order_packed``)."""
     J = jobs.capacity
-    perm = jnp.lexsort((jnp.arange(J), jobs.arrival, -jobs.priority))
+    perm = _lexsort_i32((_f32_sort_key(jobs.arrival), _f32_sort_key(-jobs.priority)))
     return jnp.zeros((J,), jnp.int32).at[perm].set(jnp.arange(J, dtype=jnp.int32))
 
 

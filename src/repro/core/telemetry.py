@@ -392,6 +392,27 @@ def run_manifest(
     return m
 
 
+REPO_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Keep compiled programs in JAX's persistent cache across processes.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and this
+    sets nothing.  Otherwise the cache goes to ``<repo>/.jax_cache``: a fixed
+    path, since the path is part of the cache key.  Returns the directory in
+    use.  Entry points call this; importing ``repro`` does not."""
+    import os
+
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_CACHE_DIR))
+    return str(REPO_CACHE_DIR)
+
+
 def manifest_path(artifact_path) -> pathlib.Path:
     """Sidecar path convention: ``run.ndjson`` -> ``run.ndjson.manifest.json``."""
     p = pathlib.Path(artifact_path)

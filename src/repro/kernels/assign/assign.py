@@ -28,6 +28,21 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def exclusive_prefix_sum(w):
+    """Exclusive running sum of ``w [bn, E]`` down the rows, in a kernel.
+
+    Mosaic has no cumsum, so the sum is a strictly-lower-triangular
+    ``[bn, bn]`` matmul.  At ``HIGHEST`` precision every f32 operand is split
+    into exact bf16 parts and accumulated in f32, so integer core counts sum
+    exactly (below 2**24), as the oracle's ``jnp.cumsum`` does."""
+    bn = w.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1)
+    tri = (col < row).astype(jnp.float32)
+    return jnp.dot(tri, w, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 def _assign_kernel(
     scores_ref,  # [bn, E] f32 VMEM
     sizes_ref,   # [bn, 1] f32 VMEM
@@ -68,7 +83,7 @@ def _assign_kernel(
         ok = best_val > NEG_INF / 2                                # [bn, 1]
         onehot = (iota_e == idx) & ok                              # [bn, E]
         w = jnp.where(onehot, sz, 0.0)                             # [bn, E]
-        cum_excl = jnp.cumsum(w, axis=0) - w                       # [bn, E]
+        cum_excl = exclusive_prefix_sum(w)                         # [bn, E]
         pos = jnp.sum(jnp.where(onehot, cum_excl + used, 0.0), axis=-1, keepdims=True)
         admit = ok & (pos + sz <= jnp.sum(jnp.where(onehot, caps, 0.0), -1, keepdims=True) + 1e-6)
         used = used + jnp.sum(w, axis=0, keepdims=True)            # FIFO claims

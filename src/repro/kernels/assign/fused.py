@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .assign import exclusive_prefix_sum
+
 NEG_INF = -1e30
 
 
@@ -74,7 +76,7 @@ def _fused_kernel(
     iota_e = jax.lax.broadcasted_iota(jnp.int32, (bn, Ep), 1)
     onehot = (iota_e == site) & ok  # [bn, Ep]
     w = jnp.where(onehot, sz, 0.0)
-    cum_excl = jnp.cumsum(w, axis=0) - w
+    cum_excl = exclusive_prefix_sum(w)
     used = used_ref[...]
     pos = jnp.sum(jnp.where(onehot, cum_excl + used, 0.0), axis=-1, keepdims=True)
     cap_at = jnp.sum(jnp.where(onehot, caps, 0.0), axis=-1, keepdims=True)

@@ -11,32 +11,39 @@ from .assign import assign_pallas
 from .fused import fused_assign_pallas, fused_assign_ref
 from .ref import assign_ref
 
-# interpret=True on CPU (this container); compiled Mosaic on real TPU.
-_INTERPRET = jax.default_backend() != "tpu"
+
+def _resolve(use_kernel: bool | None, interpret: bool) -> bool:
+    """``use_kernel=None`` means the kernel on TPU or under ``interpret=True``,
+    and the jnp oracle otherwise.  The Pallas interpreter runs only when a
+    caller passes ``interpret=True``; the kernel off TPU without it fails to
+    lower, loudly."""
+    if use_kernel is None:
+        return interpret or jax.default_backend() == "tpu"
+    return use_kernel
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "use_kernel"))
-def assign(scores, sizes, caps, *, k: int = 1, block_n: int = 256, use_kernel: bool = True):
+@functools.partial(jax.jit, static_argnames=("k", "block_n", "use_kernel", "interpret"))
+def assign(scores, sizes, caps, *, k: int = 1, block_n: int = 256, use_kernel: bool = True,
+           interpret: bool = False):
     """Capacity-constrained greedy assignment (see assign.py for semantics)."""
     if use_kernel:
-        return assign_pallas(scores, sizes, caps, k=k, block_n=block_n, interpret=_INTERPRET)
+        return assign_pallas(scores, sizes, caps, k=k, block_n=block_n, interpret=interpret)
     return assign_ref(scores, sizes, caps, k=k, block_n=block_n)
 
 
 def make_capacity_assign(
-    jobs_cores: jax.Array | None = None, *, use_kernel: bool | None = None, block_n: int = 256
+    jobs_cores: jax.Array | None = None, *, use_kernel: bool | None = None,
+    interpret: bool = False, block_n: int = 256
 ):
     """Build an engine-compatible ``Policy.assign`` fn: jobs -> sites under
     free-core capacity; jobs beyond capacity stay QUEUED at the main server.
 
     ``use_kernel=None`` (the default) resolves by backend: the compiled
-    Mosaic kernel on TPU, the jnp oracle elsewhere (pallas interpret mode
-    inside the engine's while_loop is CPU-slow).  Pass an explicit bool to
-    override either way — e.g. ``True`` on CPU runs the kernel in interpret
-    mode, the CI smoke configuration (``bench_assign_kernel --tiny``).
+    Mosaic kernel on TPU, the jnp oracle elsewhere.  ``use_kernel=False``
+    forces the oracle; ``interpret=True`` runs the kernel in the Pallas
+    interpreter, the CPU smoke configuration (``bench_assign_kernel --tiny``).
     """
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+    use_kernel = _resolve(use_kernel, interpret)
 
     def assign_fn(scores, queued, feasible, sites):
         NEG = jnp.float32(-1e30)
@@ -47,7 +54,8 @@ def make_capacity_assign(
         sizes = jnp.where(queued, sizes, 0.0)
         caps = jnp.where(sites.active, sites.free_cores, 0).astype(jnp.float32)
         idx, gate, admit, pos = assign(
-            masked, sizes, caps, k=1, block_n=block_n, use_kernel=use_kernel
+            masked, sizes, caps, k=1, block_n=block_n, use_kernel=use_kernel,
+            interpret=interpret,
         )
         ok = admit[:, 0] & queued
         return jnp.where(ok, idx[:, 0], -1), ok
@@ -55,18 +63,20 @@ def make_capacity_assign(
     return assign_fn
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "use_kernel"))
-def fused_topk_assign(scores_k, cand, sizes, caps, *, block_n: int = 256, use_kernel: bool = True):
+@functools.partial(jax.jit, static_argnames=("block_n", "use_kernel", "interpret"))
+def fused_topk_assign(scores_k, cand, sizes, caps, *, block_n: int = 256, use_kernel: bool = True,
+                      interpret: bool = False):
     """Fused candidate-set rank + capacity pick (see fused.py for semantics)."""
     if use_kernel:
         return fused_assign_pallas(
-            scores_k, cand, sizes, caps, block_n=block_n, interpret=_INTERPRET
+            scores_k, cand, sizes, caps, block_n=block_n, interpret=interpret
         )
     return fused_assign_ref(scores_k, cand, sizes, caps, block_n=block_n)
 
 
 def make_fused_capacity_assign(
-    jobs_cores: jax.Array | None = None, *, use_kernel: bool | None = None, block_n: int = 256
+    jobs_cores: jax.Array | None = None, *, use_kernel: bool | None = None,
+    interpret: bool = False, block_n: int = 256
 ):
     """Build an engine-compatible ``Policy.assign_cand`` fn for sparse top-k
     mode (engine ``topk=``): rank the per-job candidate set and admit under
@@ -74,13 +84,10 @@ def make_fused_capacity_assign(
     dense ``[J, S]`` masked-score matrix that ``make_capacity_assign`` builds.
 
     With candidates covering all feasible sites (``topk >= S``) the result is
-    bit-for-bit equal to the dense ``make_capacity_assign`` path.  Backend
-    dispatch matches ``make_capacity_assign``: ``use_kernel=None`` runs the
-    Mosaic kernel on TPU and the jnp oracle elsewhere; an explicit ``True``
-    on CPU runs the kernel in interpret mode (the CI smoke configuration).
+    bit-for-bit equal to the dense ``make_capacity_assign`` path.
+    ``use_kernel`` and ``interpret`` resolve as in ``make_capacity_assign``.
     """
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+    use_kernel = _resolve(use_kernel, interpret)
 
     def assign_cand(scores_k, queued, feas_k, cand, sites):
         S = sites.capacity
@@ -91,7 +98,8 @@ def make_fused_capacity_assign(
         sizes = jnp.where(queued, sizes, 0.0)
         caps = jnp.where(sites.active, sites.free_cores, 0).astype(jnp.float32)
         site, admit = fused_topk_assign(
-            scores_k, cand_eff, sizes, caps, block_n=block_n, use_kernel=use_kernel
+            scores_k, cand_eff, sizes, caps, block_n=block_n, use_kernel=use_kernel,
+            interpret=interpret,
         )
         ok = admit & queued
         return jnp.where(ok, site, -1), ok
@@ -99,8 +107,9 @@ def make_fused_capacity_assign(
     return assign_cand
 
 
-@functools.partial(jax.jit, static_argnames=("k", "capacity", "use_kernel", "block_n"))
-def moe_route(router_logits, *, k: int, capacity: int, use_kernel: bool = True, block_n: int = 256):
+@functools.partial(jax.jit, static_argnames=("k", "capacity", "use_kernel", "interpret", "block_n"))
+def moe_route(router_logits, *, k: int, capacity: int, use_kernel: bool = True,
+              interpret: bool = False, block_n: int = 256):
     """Token->expert routing for the MoE layer.
 
     router_logits f32[T, E] -> (expert i32[T,k], combine f32[T,k],
@@ -112,7 +121,8 @@ def moe_route(router_logits, *, k: int, capacity: int, use_kernel: bool = True, 
     sizes = jnp.ones((T,), jnp.float32)
     caps = jnp.full((E,), float(capacity), jnp.float32)
     idx, gate, admit, pos = assign(
-        router_logits, sizes, caps, k=k, block_n=block_n, use_kernel=use_kernel
+        router_logits, sizes, caps, k=k, block_n=block_n, use_kernel=use_kernel,
+        interpret=interpret,
     )
     keep = admit
     combine = gate * keep

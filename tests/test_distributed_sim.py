@@ -168,6 +168,14 @@ for a, b in zip(jax.tree.leaves(rv), jax.tree.leaves(rs)):
 rb = simulate_many_sharded(stack_scenarios(scens, buckets=3), pol,
                            jax.random.PRNGKey(5), mesh)
 assert float(np.abs(np.asarray(rb.makespan) - np.asarray(rv.makespan)).max()) == 0.0
+# lanes the caller already placed on the (Explicit-axis) mesh are never
+# donated: a second call on the same buffers still works
+from jax.sharding import NamedSharding, PartitionSpec as P
+st = stack_scenarios(scens[:4] * 2)
+placed = jax.tree.map(lambda x: jax.device_put(x, NamedSharding(mesh, P("data"))), st)
+rp1 = simulate_many_sharded(placed, pol, jax.random.PRNGKey(5), mesh)
+rp2 = simulate_many_sharded(placed, pol, jax.random.PRNGKey(5), mesh)
+assert (np.asarray(rp1.makespan) == np.asarray(rp2.makespan)).all()
 print("DIST-OK")
 """
 

@@ -15,12 +15,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 assert len(jax.devices()) == 8
-if hasattr(jax.sharding, "AxisType"):
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
-else:  # jax <= 0.4.x: no explicit-sharding axis types
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-from repro.core.distributed import use_mesh
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
 from repro.configs import get_smoke
 from repro.models import build_model
@@ -48,7 +44,7 @@ for arch in ["deepseek-7b", "kimi-k2-1t-a32b", "mamba2-130m", "recurrentgemma-2b
     batch = {"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32,
              sharding=NamedSharding(mesh, P(("pod", "data"), None)))}
     step = make_train_step(model, AdamWConfig(), microbatches=2)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, donate_argnums=0).lower(state_structs, batch)
         compiled = lowered.compile()
     mem = compiled.memory_analysis()
@@ -70,7 +66,7 @@ for arch in ["deepseek-7b", "kimi-k2-1t-a32b", "mamba2-130m", "recurrentgemma-2b
     tok = jax.ShapeDtypeStruct((B, 1), jnp.int32,
                                sharding=NamedSharding(mesh, P(("pod", "data"), None)))
     decode = make_decode_step(model)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         dec_compiled = jax.jit(lambda p, t, c: decode(p, t, c),
                                donate_argnums=2).lower(pstructs, tok, cstructs).compile()
     assert dec_compiled.memory_analysis() is not None

@@ -97,12 +97,15 @@ def test_engine_fused_topk_full_equals_dense_capacity_assign():
         base, make_capacity_assign(jobs_cores=jobs.cores, use_kernel=False)
     )
     res_dense = simulate(jobs, sites, dense_pol, key)
-    for use_kernel in (False, True):  # jnp oracle, interpret-mode kernel
+    for interpret in (False, True):  # jnp oracle, interpret-mode kernel
         fused_pol = with_fused_assign(
-            base, make_fused_capacity_assign(jobs_cores=jobs.cores, use_kernel=use_kernel)
+            base,
+            make_fused_capacity_assign(
+                jobs_cores=jobs.cores, use_kernel=interpret, interpret=interpret
+            ),
         )
         res_fused = simulate(jobs, sites, fused_pol, key, topk=sites.capacity)
-        assert _trees_equal(res_dense, res_fused), f"use_kernel={use_kernel}"
+        assert _trees_equal(res_dense, res_fused), f"interpret={interpret}"
 
 
 def test_engine_fused_small_k_runs_and_completes():

@@ -61,12 +61,11 @@ def _snapshot_one(res) -> dict:
     )
 
 
-def compute_snapshot() -> dict:
+def trace_cases() -> list:
+    """The golden-trace scenarios as ``(name, jobs, sites, simulate kwargs)``,
+    all run with the ``panda_dispatch`` policy and ``PRNGKey(0)``."""
     jobs = synthetic_panda_jobs(60, seed=11, duration=900.0)
     sites = atlas_like_platform(4, seed=12, fail_rate=0.05)
-    pol = get_policy("panda_dispatch")
-    key = jax.random.PRNGKey(0)
-    base = simulate(jobs, sites, pol, key)
     # site 3 carries the whole workload under this seed: hit it mid-run
     av = make_availability(
         4,
@@ -75,8 +74,16 @@ def compute_snapshot() -> dict:
             dict(site=2, start=500.0, end=5000.0, factor=0.5),
         ],
     )
-    outage = simulate(jobs, sites, pol, key, availability=av)
-    return dict(baseline=_snapshot_one(base), outage=_snapshot_one(outage))
+    return [("baseline", jobs, sites, {}), ("outage", jobs, sites, {"availability": av})]
+
+
+def compute_snapshot() -> dict:
+    pol = get_policy("panda_dispatch")
+    key = jax.random.PRNGKey(0)
+    return {
+        name: _snapshot_one(simulate(jobs, sites, pol, key, **kw))
+        for name, jobs, sites, kw in trace_cases()
+    }
 
 
 def test_golden_trace_exact():
@@ -205,17 +212,17 @@ def combo_kwargs(scn: dict, data: bool, avail: bool, wf: bool):
     return jobs, kw
 
 
-def compute_matrix_snapshot() -> dict:
+def matrix_cases() -> list:
+    """Every golden-matrix combo as ``(name, jobs, sites, simulate kwargs)``,
+    all run with the ``panda_dispatch`` policy and ``PRNGKey(0)``."""
     scn = matrix_scenario()
-    pol = get_policy("panda_dispatch")
-    key = jax.random.PRNGKey(0)
-    out = {}
+    out = []
     for data, avail, wf in itertools.product((False, True), repeat=3):
         name = "+".join(
             n for n, on in (("data", data), ("avail", avail), ("wf", wf)) if on
         ) or "plain"
         jobs, kw = combo_kwargs(scn, data, avail, wf)
-        out[name] = _snapshot_combo(simulate(jobs, scn["sites"], pol, key, **kw))
+        out.append((name, jobs, scn["sites"], kw))
     # transfer-queue combos (ISSUE 8): the queued WAN model rides on the data
     # subsystem, so only the data-on half of the matrix composes with it
     for avail, wf in itertools.product((False, True), repeat=2):
@@ -225,7 +232,7 @@ def compute_matrix_snapshot() -> dict:
         )
         jobs, kw = combo_kwargs(scn, True, avail, wf)
         kw["transfers"] = make_transfers(4, jobs.capacity, max_active=2)
-        out[name] = _snapshot_combo(simulate(jobs, scn["sites"], pol, key, **kw))
+        out.append((name, jobs, scn["sites"], kw))
     # fault-injection combos (ISSUE 10): all four channels armed at once —
     # flaky WAN links, resubmission backoff, walltime kills, replica loss
     # targeting cached (non-origin) copies, and the circuit breaker
@@ -250,8 +257,17 @@ def compute_matrix_snapshot() -> dict:
         if data:
             kw["transfers"] = make_transfers(4, jobs.capacity, max_active=2)
         kw["faults"] = faults_state(jobs)
-        out[name] = _snapshot_combo(simulate(jobs, scn["sites"], pol, key, **kw))
+        out.append((name, jobs, scn["sites"], kw))
     return out
+
+
+def compute_matrix_snapshot() -> dict:
+    pol = get_policy("panda_dispatch")
+    key = jax.random.PRNGKey(0)
+    return {
+        name: _snapshot_combo(simulate(jobs, sites, pol, key, **kw))
+        for name, jobs, sites, kw in matrix_cases()
+    }
 
 
 def test_golden_matrix_exact():

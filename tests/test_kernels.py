@@ -49,7 +49,7 @@ def test_assign_respects_capacity_exactly():
     scores = jnp.zeros((N, 4)).at[:, 0].set(10.0)
     sizes = jnp.full((N,), 3.0)
     caps = jnp.array([10.0, 100.0, 100.0, 100.0])
-    idx, gate, admit, pos = assign(scores, sizes, caps, k=1, use_kernel=True)
+    idx, gate, admit, pos = assign(scores, sizes, caps, k=1, use_kernel=True, interpret=True)
     assert int(admit.sum()) == 3
     assert (np.asarray(idx)[:, 0] == 0).all()
     np.testing.assert_allclose(np.asarray(pos)[:4, 0], [0.0, 3.0, 6.0, 9.0])
@@ -57,7 +57,7 @@ def test_assign_respects_capacity_exactly():
 
 def test_assign_infeasible_rows():
     scores = jnp.full((8, 4), -1e30)
-    idx, gate, admit, pos = assign(scores, jnp.ones(8), jnp.full(4, 100.0), k=2)
+    idx, gate, admit, pos = assign(scores, jnp.ones(8), jnp.full(4, 100.0), k=2, interpret=True)
     assert (np.asarray(idx) == -1).all()
     assert not np.asarray(admit).any()
     assert (np.asarray(gate) == 0).all()
@@ -66,7 +66,7 @@ def test_assign_infeasible_rows():
 def test_moe_route_slots_unique_per_expert():
     T, E, k, cap = 256, 16, 2, 24
     logits = jax.random.normal(jax.random.PRNGKey(0), (T, E))
-    idx, combine, slot, keep = moe_route(logits, k=k, capacity=cap)
+    idx, combine, slot, keep = moe_route(logits, k=k, capacity=cap, interpret=True)
     idx, slot, keep = map(np.asarray, (idx, slot, keep))
     # kept (expert, slot) pairs must be unique and < capacity
     pairs = [(int(e), int(s)) for e, s, kp in

@@ -167,6 +167,23 @@ def test_packed_start_order_matches_lexsort():
         assert (np.asarray(batched[lane]) == np.asarray(solo)).all()
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_static_start_rank_matches_lexsort(seed):
+    """The static rank's single-key i32 sorts reproduce the 3-key float
+    lexsort, including ties, signed zeros, infinities and NaN."""
+    rng = np.random.default_rng(seed)
+    J = 700
+    edge = np.array([0.0, -0.0, 1.5, -3.0, np.inf, -np.inf, np.nan, 1e-30, -1e-30],
+                    np.float32)
+    jobs = synthetic_panda_jobs(J, seed=seed)
+    arrival = rng.choice(edge, J) if seed else np.asarray(jobs.arrival)
+    priority = rng.choice(edge, J) if seed != 1 else np.asarray(jobs.priority)
+    jobs = jobs._replace(arrival=jnp.asarray(arrival), priority=jnp.asarray(priority))
+    perm = jnp.lexsort((jnp.arange(J), jobs.arrival, -jobs.priority))
+    ref = jnp.zeros((J,), jnp.int32).at[perm].set(jnp.arange(J, dtype=jnp.int32))
+    assert (np.asarray(_static_start_rank(jobs)) == np.asarray(ref)).all()
+
+
 def test_rank_policy_disables_packed_order():
     """Policies with a dynamic rank hook must keep the general lexsort."""
     pol = get_policy("critical_path_first")

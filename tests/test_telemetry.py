@@ -273,3 +273,28 @@ def test_padding_stats_bucketed_beats_flat():
     assert s["waste_frac"] < s["flat_waste_frac"]
     for r in stats["buckets"]:
         assert 0.0 <= r["waste_frac"] < 1.0
+
+
+@pytest.mark.parametrize("env_dir", ["/elsewhere/jax-cache", None])
+def test_enable_compile_cache(monkeypatch, env_dir):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins and no config changes; without it
+    the cache goes to the fixed ``<repo>/.jax_cache``."""
+    import pathlib
+
+    from repro.core.telemetry import enable_compile_cache
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    sentinel = "/unchanged"
+    try:
+        jax.config.update("jax_compilation_cache_dir", sentinel)
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            assert enable_compile_cache() == str(repo / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == str(repo / ".jax_cache")
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+            assert enable_compile_cache() == env_dir
+            assert jax.config.jax_compilation_cache_dir == sentinel
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
