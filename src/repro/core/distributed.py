@@ -33,6 +33,7 @@ from .engine import (
     simulate_many,
     stack_scenarios,
 )
+from .telemetry import span
 from .types import JobsState, SimResult, SiteState
 
 
@@ -323,54 +324,29 @@ def simulate_many_sharded(
     default) or ``"vmap"`` (SIMD batching — the accelerator default);
     ``"auto"`` resolves by backend.
 
-    Pass a ``telemetry.TraceRecorder`` as ``recorder`` to instrument the run:
-    stack/run wall-clock spans, lane and mesh gauges, per-lane round spread,
-    and (for bucketed input) the measured padding-waste breakdown from
-    ``ScenarioBuckets.padding_stats`` — the numbers behind the PR 5 win.
+    Stacking and the run are ``ensemble_stack`` and ``ensemble_run`` spans
+    (``telemetry.span``).  Pass a ``telemetry.TraceRecorder`` as ``recorder``
+    to time them (the run span then waits for the result) and, for bucketed
+    input, to note the padding-waste breakdown from
+    ``ScenarioBuckets.padding_stats``.
     """
     mesh = auto_mesh(mesh)
     runner = lambda scen, keys: _sharded_stacked(  # noqa: E731
         scen, keys, policy, mesh, axis, subsystems, donate, lane_mode, kw
     )
-    if recorder is None:
+    if not isinstance(scenarios, (Scenario, ScenarioBuckets)):
+        with span("ensemble_stack", recorder):
+            scenarios = stack_scenarios(scenarios, subsystems=subsystems)
+    if recorder is not None and isinstance(scenarios, ScenarioBuckets):
+        recorder.note("bucket_padding", scenarios.padding_stats())
+    with span("ensemble_run", recorder):
         if isinstance(scenarios, ScenarioBuckets):
-            return _run_buckets(scenarios, rng, runner, subsystems)
-        if not isinstance(scenarios, Scenario):
-            scenarios = stack_scenarios(scenarios, subsystems=subsystems)
-        K = scenarios.jobs.arrival.shape[0]
-        return runner(scenarios, jax.random.split(rng, K))
-
-    buckets = scenarios if isinstance(scenarios, ScenarioBuckets) else None
-    if buckets is None and not isinstance(scenarios, Scenario):
-        with recorder.span("ensemble_stack"):
-            scenarios = stack_scenarios(scenarios, subsystems=subsystems)
-            if isinstance(scenarios, ScenarioBuckets):  # pragma: no cover
-                buckets = scenarios
-    n_dev = mesh.shape[axis]
-    if buckets is not None:
-        lanes = [s.jobs.arrival.shape[0] for s in buckets.buckets]
-        K = sum(lanes)
-        lane_pad = sum((-k) % n_dev for k in lanes)
-        recorder.note("bucket_padding", buckets.padding_stats())
-        with recorder.span("ensemble_run"):
-            res = _run_buckets(buckets, rng, runner, subsystems)
-            jax.block_until_ready(res)
-    else:
-        K = scenarios.jobs.arrival.shape[0]
-        lane_pad = (-K) % n_dev
-        with recorder.span("ensemble_run"):
+            res = _run_buckets(scenarios, rng, runner, subsystems)
+        else:
+            K = scenarios.jobs.arrival.shape[0]
             res = runner(scenarios, jax.random.split(rng, K))
+        if recorder is not None:
             jax.block_until_ready(res)
-    import numpy as np
-
-    rounds = np.asarray(res.rounds)
-    recorder.gauge("lanes", K)
-    recorder.gauge("mesh_devices", int(mesh.devices.size))
-    recorder.gauge("lane_pad_total", lane_pad)
-    recorder.gauge("lane_rounds_min", int(rounds.min()))
-    recorder.gauge("lane_rounds_max", int(rounds.max()))
-    recorder.gauge("lane_rounds_mean", float(rounds.mean()))
-    recorder.note("lane_mode", lane_mode)
     return res
 
 

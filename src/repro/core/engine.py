@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .subsystems import RoundCtx, resolve_subsystems
+from .telemetry import span
 from .types import (
     ASSIGNED,
     DONE,
@@ -56,6 +57,14 @@ from .types import (
 )
 
 INF = jnp.float32(jnp.inf)
+
+# The round loop's phases, each a ``jax.named_scope`` in ``_round_fns``, so
+# every device op of the loop carries one of them in its HLO ``op_name``
+# (with the name of the subsystem whose hook emitted it, where one did):
+# ``cond`` and the next-event min-reduction; completions, resubmission and
+# arrivals; candidate refresh, feasibility and the policy's assignment;
+# start order, admission and service times; halt detection and the event log.
+PHASES = ("clock", "completions", "score", "start", "bookkeeping")
 
 
 def compute_time(jobs: JobsState, sites: SiteState, site: jax.Array) -> jax.Array:
@@ -452,157 +461,167 @@ def _round_fns(
     ``while_loop`` or paused-and-resumed across many."""
 
     def cond(st: EngineState, horizon):
-        active = (
-            (st.jobs.state == PENDING)
-            | (st.jobs.state == QUEUED)
-            | (st.jobs.state == ASSIGNED)
-            | (st.jobs.state == RUNNING)
-        )
-        return (
-            (~st.halted)
-            & jnp.any(active & st.jobs.valid)
-            & (st.round < max_rounds)
-            & (st.clock <= horizon)
-        )
+        with jax.named_scope("clock"):
+            active = (
+                (st.jobs.state == PENDING)
+                | (st.jobs.state == QUEUED)
+                | (st.jobs.state == ASSIGNED)
+                | (st.jobs.state == RUNNING)
+            )
+            return (
+                (~st.halted)
+                & jnp.any(active & st.jobs.valid)
+                & (st.round < max_rounds)
+                & (st.clock <= horizon)
+            )
 
     def body(st: EngineState) -> EngineState:
         S = st.sites.capacity
         J = st.jobs.capacity
         jobs, sites = st.jobs, st.sites
-        rng, k_fail, k_frac, k_policy = jax.random.split(st.rng, 4)
-        ctx = RoundCtx(
-            jobs=jobs, sites=sites, ext=dict(st.ext),
-            clock_prev=st.clock, max_retries=max_retries,
-            # per-subsystem RNG streams fold off the round's carry key (see
-            # RoundCtx.subkey); the split above is untouched, so subsystem
-            # draws never shift the engine's own bitstream
-            rng=st.rng,
-        )
+        with jax.named_scope("clock"):
+            rng, k_fail, k_frac, k_policy = jax.random.split(st.rng, 4)
+            ctx = RoundCtx(
+                jobs=jobs, sites=sites, ext=dict(st.ext),
+                clock_prev=st.clock, max_retries=max_retries,
+                # per-subsystem RNG streams fold off the round's carry key (see
+                # RoundCtx.subkey); the split above is untouched, so subsystem
+                # draws never shift the engine's own bitstream
+                rng=st.rng,
+            )
 
-        # ---- 1. advance the clock to the next event ------------------------
-        arrivable = (jobs.state == PENDING) & jobs.valid
-        for sub in subsystems:
-            if sub.arrival_gate is not None:
-                # gated jobs are not an event source: their wake-up event is
-                # whatever un-gates them (e.g. a DAG parent's completion)
-                arrivable = arrivable & sub.arrival_gate(sub, ctx)
-        arr_t = jnp.where(arrivable, jobs.arrival, INF)
-        fin_t = jnp.where(jobs.state == RUNNING, jobs.t_finish, INF)
-        t_next = jnp.minimum(arr_t.min(), fin_t.min())
-        for sub in subsystems:
-            if sub.event_times is not None:
-                # subsystem event sources (e.g. outage window edges) join the
-                # min-reduction so rounds land exactly on their boundaries
-                t_next = jnp.minimum(t_next, sub.event_times(sub, ctx))
-        if quantum > 0.0:
-            t_next = t_next + quantum
-        clock = jnp.where(jnp.isfinite(t_next), jnp.maximum(st.clock, t_next), st.clock)
-        ctx.clock = clock
+            # ---- 1. advance the clock to the next event --------------------
+            arrivable = (jobs.state == PENDING) & jobs.valid
+            for sub in subsystems:
+                if sub.arrival_gate is not None:
+                    # gated jobs are not an event source: their wake-up event is
+                    # whatever un-gates them (e.g. a DAG parent's completion)
+                    with jax.named_scope(sub.name):
+                        arrivable = arrivable & sub.arrival_gate(sub, ctx)
+            arr_t = jnp.where(arrivable, jobs.arrival, INF)
+            fin_t = jnp.where(jobs.state == RUNNING, jobs.t_finish, INF)
+            t_next = jnp.minimum(arr_t.min(), fin_t.min())
+            for sub in subsystems:
+                if sub.event_times is not None:
+                    # subsystem event sources (e.g. outage window edges) join the
+                    # min-reduction so rounds land exactly on their boundaries
+                    with jax.named_scope(sub.name):
+                        t_next = jnp.minimum(t_next, sub.event_times(sub, ctx))
+            if quantum > 0.0:
+                t_next = t_next + quantum
+            clock = jnp.where(jnp.isfinite(t_next), jnp.maximum(st.clock, t_next), st.clock)
+            ctx.clock = clock
 
-        # ---- 2. completions -------------------------------------------------
-        comp = (jobs.state == RUNNING) & (jobs.t_finish <= clock)
-        for sub in subsystems:
-            if sub.completion_filter is not None:
-                comp = sub.completion_filter(sub, ctx, comp)
-        comp_site = jnp.where(comp, jobs.site, S)  # padded segment for non-events
-        freed_mem = _site_sum(jnp.where(comp, jobs.memory, 0.0), comp_site, S)
-        failed_now = comp & jobs.will_fail
-        resubmit = failed_now & (jobs.retries < max_retries)
-        perm_fail = failed_now & ~resubmit
-        done_now = comp & ~jobs.will_fail
-        # one stacked scatter for the three int per-site completion reductions
-        comp_sums = _site_sum_stacked(
-            jnp.stack(
-                [
-                    jnp.where(comp, jobs.cores, 0),
-                    done_now.astype(jnp.int32),
-                    failed_now.astype(jnp.int32),
-                ],
-                axis=-1,
-            ),
-            comp_site,
-            S,
-        )
-        freed_cores = comp_sums[..., 0]
-
-        new_state = jobs.state
-        new_state = jnp.where(done_now, DONE, new_state)
-        new_state = jnp.where(perm_fail, FAILED, new_state)
-        new_state = jnp.where(resubmit, QUEUED, new_state)  # PanDA-style resubmission
-        jobs = jobs._replace(
-            state=new_state,
-            retries=jobs.retries + resubmit.astype(jnp.int32),
-            site=jnp.where(resubmit, -1, jobs.site),
-            t_finish=jnp.where(resubmit, INF, jobs.t_finish),
-        )
-        sites = sites._replace(
-            free_cores=sites.free_cores + freed_cores,
-            free_memory=sites.free_memory + freed_mem,
-            n_finished=sites.n_finished + comp_sums[..., 1],
-            n_failed=sites.n_failed + comp_sums[..., 2],
-        )
-        ctx.jobs, ctx.sites = jobs, sites
-        ctx.comp, ctx.done_now, ctx.failed_now = comp, done_now, failed_now
-
-        # ---- 2b. subsystem post-completion transitions -----------------------
-        # (availability preemption/brown-out, workflow cascade-cancel, ...)
-        for sub in subsystems:
-            if sub.on_completions is not None:
-                sub.on_completions(sub, ctx)
-        jobs, sites = ctx.jobs, ctx.sites
-
-        # ---- 3. arrivals -----------------------------------------------------
-        arrived = (jobs.state == PENDING) & (jobs.arrival <= clock) & jobs.valid
-        for sub in subsystems:
-            if sub.arrival_gate is not None:
-                # re-gate against post-completion states so a job un-gated
-                # *this round* arrives (and can start) this round
-                arrived = arrived & sub.arrival_gate(sub, ctx)
-        jobs = jobs._replace(state=jnp.where(arrived, QUEUED, jobs.state))
-        ctx.jobs, ctx.arrived = jobs, arrived
-
-        # ---- 4+5. assignment & starts -----------------------------------------
-        queued = jobs.state == QUEUED
-        if topk is not None and topk_refresh > 0:
-            # periodic candidate rebuild (DESIGN.md §12): O(J*S) behind a
-            # scalar cond so non-refresh rounds never touch dense shapes.
-            # ``_ensemble_any`` keeps the cond scalar under vmap — lanes of
-            # an ensemble therefore refresh on shared rounds (exact only at
-            # k >= S, where rebuilds are idempotent).
-            from .sparse import CAND_SALT, build_candidates
-
-            do_refresh = _ensemble_any(jnp.mod(st.round, topk_refresh) == 0)
-            ctx.ext["~cand"] = jax.lax.cond(
-                do_refresh,
-                lambda ops: build_candidates(
-                    ops[0], ops[1], policy, st.policy_state, clock,
-                    jax.random.fold_in(st.rng, CAND_SALT), ctx.ext, topk,
+        with jax.named_scope("completions"):
+            # ---- 2. completions ---------------------------------------------
+            comp = (jobs.state == RUNNING) & (jobs.t_finish <= clock)
+            for sub in subsystems:
+                if sub.completion_filter is not None:
+                    with jax.named_scope(sub.name):
+                        comp = sub.completion_filter(sub, ctx, comp)
+            comp_site = jnp.where(comp, jobs.site, S)  # padded segment for non-events
+            freed_mem = _site_sum(jnp.where(comp, jobs.memory, 0.0), comp_site, S)
+            failed_now = comp & jobs.will_fail
+            resubmit = failed_now & (jobs.retries < max_retries)
+            perm_fail = failed_now & ~resubmit
+            done_now = comp & ~jobs.will_fail
+            # one stacked scatter for the three int per-site completion reductions
+            comp_sums = _site_sum_stacked(
+                jnp.stack(
+                    [
+                        jnp.where(comp, jobs.cores, 0),
+                        done_now.astype(jnp.int32),
+                        failed_now.astype(jnp.int32),
+                    ],
+                    axis=-1,
                 ),
-                lambda ops: ctx.ext["~cand"],
-                (jobs, sites),
+                comp_site,
+                S,
             )
-        if topk is None:
-            # static feasibility: job can ever fit the site
-            ctx.feasible = (
-                sites.active[None, :]
-                & (jobs.cores[:, None] <= sites.cores[None, :])
-                & (jobs.memory[:, None] <= sites.memory[None, :])
+            freed_cores = comp_sums[..., 0]
+
+            new_state = jobs.state
+            new_state = jnp.where(done_now, DONE, new_state)
+            new_state = jnp.where(perm_fail, FAILED, new_state)
+            new_state = jnp.where(resubmit, QUEUED, new_state)  # PanDA-style resubmission
+            jobs = jobs._replace(
+                state=new_state,
+                retries=jobs.retries + resubmit.astype(jnp.int32),
+                site=jnp.where(resubmit, -1, jobs.site),
+                t_finish=jnp.where(resubmit, INF, jobs.t_finish),
             )
-        else:
-            # sparse mode: the static core/memory fit lives in the candidate
-            # index; per-round feasibility starts as a per-site [1, S] mask
-            # that pre_assign hooks compose with [None, :]-broadcast masks
-            # (availability does).  A hook may still write a full [J, S] —
-            # the gather below dispatches on the leading dim.
-            ctx.feasible = sites.active[None, :]
-        ctx.start_cores = sites.free_cores
-        ctx.sites_serv = sites
-        for sub in subsystems:
-            if sub.pre_assign is not None:
-                sub.pre_assign(sub, ctx)
-        pstate = st.policy_state
-        rank_fn = getattr(policy, "rank", None)
-        feasible, start_cores = ctx.feasible, ctx.start_cores
+            sites = sites._replace(
+                free_cores=sites.free_cores + freed_cores,
+                free_memory=sites.free_memory + freed_mem,
+                n_finished=sites.n_finished + comp_sums[..., 1],
+                n_failed=sites.n_failed + comp_sums[..., 2],
+            )
+            ctx.jobs, ctx.sites = jobs, sites
+            ctx.comp, ctx.done_now, ctx.failed_now = comp, done_now, failed_now
+
+            # ---- 2b. subsystem post-completion transitions -------------------
+            # (availability preemption/brown-out, workflow cascade-cancel, ...)
+            for sub in subsystems:
+                if sub.on_completions is not None:
+                    with jax.named_scope(sub.name):
+                        sub.on_completions(sub, ctx)
+            jobs, sites = ctx.jobs, ctx.sites
+
+            # ---- 3. arrivals -------------------------------------------------
+            arrived = (jobs.state == PENDING) & (jobs.arrival <= clock) & jobs.valid
+            for sub in subsystems:
+                if sub.arrival_gate is not None:
+                    # re-gate against post-completion states so a job un-gated
+                    # *this round* arrives (and can start) this round
+                    with jax.named_scope(sub.name):
+                        arrived = arrived & sub.arrival_gate(sub, ctx)
+            jobs = jobs._replace(state=jnp.where(arrived, QUEUED, jobs.state))
+            ctx.jobs, ctx.arrived = jobs, arrived
+
+        with jax.named_scope("score"):
+            # ---- 4+5. assignment & starts ------------------------------------
+            queued = jobs.state == QUEUED
+            if topk is not None and topk_refresh > 0:
+                # periodic candidate rebuild (DESIGN.md §12): O(J*S) behind a
+                # scalar cond so non-refresh rounds never touch dense shapes.
+                # ``_ensemble_any`` keeps the cond scalar under vmap — lanes of
+                # an ensemble therefore refresh on shared rounds (exact only at
+                # k >= S, where rebuilds are idempotent).
+                from .sparse import CAND_SALT, build_candidates
+
+                do_refresh = _ensemble_any(jnp.mod(st.round, topk_refresh) == 0)
+                ctx.ext["~cand"] = jax.lax.cond(
+                    do_refresh,
+                    lambda ops: build_candidates(
+                        ops[0], ops[1], policy, st.policy_state, clock,
+                        jax.random.fold_in(st.rng, CAND_SALT), ctx.ext, topk,
+                    ),
+                    lambda ops: ctx.ext["~cand"],
+                    (jobs, sites),
+                )
+            if topk is None:
+                # static feasibility: job can ever fit the site
+                ctx.feasible = (
+                    sites.active[None, :]
+                    & (jobs.cores[:, None] <= sites.cores[None, :])
+                    & (jobs.memory[:, None] <= sites.memory[None, :])
+                )
+            else:
+                # sparse mode: the static core/memory fit lives in the candidate
+                # index; per-round feasibility starts as a per-site [1, S] mask
+                # that pre_assign hooks compose with [None, :]-broadcast masks
+                # (availability does).  A hook may still write a full [J, S] —
+                # the gather below dispatches on the leading dim.
+                ctx.feasible = sites.active[None, :]
+            ctx.start_cores = sites.free_cores
+            ctx.sites_serv = sites
+            for sub in subsystems:
+                if sub.pre_assign is not None:
+                    with jax.named_scope(sub.name):
+                        sub.pre_assign(sub, ctx)
+            pstate = st.policy_state
+            rank_fn = getattr(policy, "rank", None)
+            feasible, start_cores = ctx.feasible, ctx.start_cores
 
         def _assign_and_start(ops):
             """Phases 4 (policy assignment, the plugin hot spot) and 5
@@ -611,79 +630,81 @@ def _round_fns(
             here is a masked no-op, which is what makes the phase-skip guard
             below bit-for-bit safe."""
             jobs, sites = ops
-            if topk is None:
-                scores = policy.score(jobs, sites, pstate, clock, k_policy)  # [J, S]
-                site_pick, assigned_now = policy.assign(scores, queued, feasible, sites)
-            else:
-                cand = ctx.ext["~cand"]                     # i32[J, K]
-                cand_c = jnp.minimum(cand, S - 1)
-                # re-check everything the dense mask carries, gathered at the
-                # candidates: validity, per-round dynamic feasibility, and the
-                # static core/memory fit (exact at k=S, where ``cand``
-                # enumerates every statically feasible site)
-                f_at = (
-                    feasible[0][cand_c]
-                    if feasible.shape[0] == 1
-                    else jnp.take_along_axis(feasible, cand_c, axis=-1)
-                )
-                feas_k = (
-                    (cand < S)
-                    & f_at
-                    & (jobs.cores[:, None] <= sites.cores[cand_c])
-                    & (jobs.memory[:, None] <= sites.memory[cand_c])
-                )
-                score_c = getattr(policy, "score_cand", None)
-                if score_c is not None:
-                    scores_k = score_c(jobs, sites, pstate, clock, k_policy, cand_c)
+            with jax.named_scope("score"):
+                if topk is None:
+                    scores = policy.score(jobs, sites, pstate, clock, k_policy)  # [J, S]
+                    site_pick, assigned_now = policy.assign(scores, queued, feasible, sites)
                 else:
-                    # exact fallback: dense score + gather (no memory win)
-                    scores_k = jnp.take_along_axis(
-                        policy.score(jobs, sites, pstate, clock, k_policy), cand_c, axis=-1
+                    cand = ctx.ext["~cand"]                     # i32[J, K]
+                    cand_c = jnp.minimum(cand, S - 1)
+                    # re-check everything the dense mask carries, gathered at the
+                    # candidates: validity, per-round dynamic feasibility, and the
+                    # static core/memory fit (exact at k=S, where ``cand``
+                    # enumerates every statically feasible site)
+                    f_at = (
+                        feasible[0][cand_c]
+                        if feasible.shape[0] == 1
+                        else jnp.take_along_axis(feasible, cand_c, axis=-1)
                     )
-                assign_c = getattr(policy, "assign_cand", None) or default_assign_cand
-                site_pick, assigned_now = assign_c(scores_k, queued, feas_k, cand_c, sites)
-            assigned_now = assigned_now & queued
-            jobs = jobs._replace(
-                state=jnp.where(assigned_now, ASSIGNED, jobs.state),
-                site=jnp.where(assigned_now, site_pick, jobs.site),
-                t_assign=jnp.where(assigned_now, clock, jobs.t_assign),
-            )
-            asg_site = jnp.where(assigned_now, site_pick, S)
-            sites = sites._replace(
-                n_assigned=sites.n_assigned
-                + _site_sum(assigned_now.astype(jnp.int32), asg_site, S)
-            )
-
-            cand = jobs.state == ASSIGNED
-            sort_site = jnp.where(cand, jobs.site, S).astype(jnp.int32)
-            if "~srank" in st.ext:
-                # packed fast path: one single-key sort, provably the same
-                # permutation as the 5-key lexsort (see _start_order_packed)
-                order = _start_order_packed(sort_site * J + ctx.ext["~srank"])
-            else:
-                # policy rank is a secondary start-order key: priority still
-                # dominates, rank breaks ties before arrival time (a rank-less
-                # policy contributes a constant key, which the stable lexsort
-                # ignores)
-                rank_val = (
-                    jnp.zeros((J,), jnp.float32) if rank_fn is None
-                    else rank_fn(jobs, sites, pstate, clock)
+                    feas_k = (
+                        (cand < S)
+                        & f_at
+                        & (jobs.cores[:, None] <= sites.cores[cand_c])
+                        & (jobs.memory[:, None] <= sites.memory[cand_c])
+                    )
+                    score_c = getattr(policy, "score_cand", None)
+                    if score_c is not None:
+                        scores_k = score_c(jobs, sites, pstate, clock, k_policy, cand_c)
+                    else:
+                        # exact fallback: dense score + gather (no memory win)
+                        scores_k = jnp.take_along_axis(
+                            policy.score(jobs, sites, pstate, clock, k_policy), cand_c, axis=-1
+                        )
+                    assign_c = getattr(policy, "assign_cand", None) or default_assign_cand
+                    site_pick, assigned_now = assign_c(scores_k, queued, feas_k, cand_c, sites)
+                assigned_now = assigned_now & queued
+                jobs = jobs._replace(
+                    state=jnp.where(assigned_now, ASSIGNED, jobs.state),
+                    site=jnp.where(assigned_now, site_pick, jobs.site),
+                    t_assign=jnp.where(assigned_now, clock, jobs.t_assign),
                 )
-                order = _start_order(sort_site, jobs.priority, rank_val, jobs.arrival)
-            site_s = sort_site[order]
-            cand_s = cand[order]
-            cores_s = jnp.where(cand_s, jobs.cores[order], 0).astype(jnp.int32)
-            mem_s = jnp.where(cand_s, jobs.memory[order], 0.0)
-            cum_cores = _segment_exclusive_base(cores_s, site_s, S + 1)
-            cum_mem = _segment_exclusive_base(mem_s, site_s, S + 1)
-            fits = (
-                cand_s
-                & (cum_cores <= start_cores[jnp.minimum(site_s, S - 1)])
-                & (cum_mem <= sites.free_memory[jnp.minimum(site_s, S - 1)] + 1e-6)
-                & (site_s < S)
-            )
-            started = jnp.zeros((J,), bool).at[order].set(fits)
-            return jobs, sites, started
+                asg_site = jnp.where(assigned_now, site_pick, S)
+                sites = sites._replace(
+                    n_assigned=sites.n_assigned
+                    + _site_sum(assigned_now.astype(jnp.int32), asg_site, S)
+                )
+
+            with jax.named_scope("start"):
+                cand = jobs.state == ASSIGNED
+                sort_site = jnp.where(cand, jobs.site, S).astype(jnp.int32)
+                if "~srank" in st.ext:
+                    # packed fast path: one single-key sort, provably the same
+                    # permutation as the 5-key lexsort (see _start_order_packed)
+                    order = _start_order_packed(sort_site * J + ctx.ext["~srank"])
+                else:
+                    # policy rank is a secondary start-order key: priority still
+                    # dominates, rank breaks ties before arrival time (a rank-less
+                    # policy contributes a constant key, which the stable lexsort
+                    # ignores)
+                    rank_val = (
+                        jnp.zeros((J,), jnp.float32) if rank_fn is None
+                        else rank_fn(jobs, sites, pstate, clock)
+                    )
+                    order = _start_order(sort_site, jobs.priority, rank_val, jobs.arrival)
+                site_s = sort_site[order]
+                cand_s = cand[order]
+                cores_s = jnp.where(cand_s, jobs.cores[order], 0).astype(jnp.int32)
+                mem_s = jnp.where(cand_s, jobs.memory[order], 0.0)
+                cum_cores = _segment_exclusive_base(cores_s, site_s, S + 1)
+                cum_mem = _segment_exclusive_base(mem_s, site_s, S + 1)
+                fits = (
+                    cand_s
+                    & (cum_cores <= start_cores[jnp.minimum(site_s, S - 1)])
+                    & (cum_mem <= sites.free_memory[jnp.minimum(site_s, S - 1)] + 1e-6)
+                    & (site_s < S)
+                )
+                started = jnp.zeros((J,), bool).at[order].set(fits)
+                return jobs, sites, started
 
         if phase_skip:
             # phase-skip guard (DESIGN.md §8): completion-only rounds — the
@@ -692,133 +713,138 @@ def _round_fns(
             # entirely.  ``_ensemble_any`` reduces the predicate over the
             # whole vmap batch, so the cond stays scalar (a real branch, not
             # a select) inside ensembles and mesh shards alike.
-            has_work = _ensemble_any(jnp.any(queued | (jobs.state == ASSIGNED)))
-            jobs, sites, started = jax.lax.cond(
-                has_work,
-                _assign_and_start,
-                lambda ops: (ops[0], ops[1], jnp.zeros((J,), bool)),
-                (jobs, sites),
-            )
+            with jax.named_scope("score"):
+                has_work = _ensemble_any(jnp.any(queued | (jobs.state == ASSIGNED)))
+                jobs, sites, started = jax.lax.cond(
+                    has_work,
+                    _assign_and_start,
+                    lambda ops: (ops[0], ops[1], jnp.zeros((J,), bool)),
+                    (jobs, sites),
+                )
         else:
             jobs, sites, started = _assign_and_start((jobs, sites))
         ctx.jobs, ctx.sites = jobs, sites
 
-        start_site = jnp.where(started, jobs.site, S)
-        start_sums = _site_sum_stacked(
-            jnp.stack(
-                [jnp.where(started, jobs.cores, 0), started.astype(jnp.int32)], axis=-1
-            ),
-            start_site,
-            S,
-        )
-        used_cores = start_sums[..., 0]
-        used_mem = _site_sum(jnp.where(started, jobs.memory, 0.0), start_site, S)
-        n_start_per_site = start_sums[..., 1]
-        site_c = jnp.minimum(jobs.site, S - 1)
-        share = n_start_per_site[site_c].astype(jnp.float32)
-
-        # ---- 5b. service times + subsystem adjustments -----------------------
-        ctx.started, ctx.site_c = started, site_c
-        ctx.share, ctx.start_site = share, start_site
-        ctx.t_serv = service_time(jobs, ctx.sites_serv, site_c, share, share)
-        for sub in subsystems:
-            if sub.on_start is not None:
-                # e.g. workflow output materialization, then replica-aware
-                # stage-in repricing (DESIGN.md §3/§6) — tuple order matters
-                sub.on_start(sub, ctx)
-        jobs = ctx.jobs
-        t_serv = ctx.t_serv
-
-        u_fail = jax.random.uniform(k_fail, (J,))
-        # clip (not minimum): unassigned rows carry site == -1, and minimum
-        # would map them to the *last* site's fail rate — masked by `started`
-        # today, but an OOB/NaN-hygiene hazard under refactors
-        will_fail = started & (u_fail < sites.fail_rate[jnp.clip(jobs.site, 0, S - 1)])
-        # a failing attempt dies partway through its service time
-        frac = jax.random.uniform(k_frac, (J,), minval=0.05, maxval=1.0)
-        t_fin = clock + jnp.where(will_fail, t_serv * frac, t_serv)
-
-        jobs = jobs._replace(
-            state=jnp.where(started, RUNNING, jobs.state),
-            t_start=jnp.where(started, clock, jobs.t_start),
-            t_finish=jnp.where(started, t_fin, jobs.t_finish),
-            will_fail=jnp.where(started, will_fail, jobs.will_fail),
-        )
-        sites = sites._replace(
-            free_cores=sites.free_cores - used_cores,
-            free_memory=sites.free_memory - used_mem,
-        )
-        ctx.jobs, ctx.sites = jobs, sites
-
-        pstate = policy.on_step(pstate, jobs, sites, comp, started, clock)
-
-        # ---- 6. halt detection & event log -----------------------------------
-        n_started = started.sum()
-        n_completed = comp.sum()
-        # subsystem transitions (preemption, cascade rounds) count as progress
-        # so halt detection gives the dispatcher a round to react to them
-        progressed = (n_started > 0) | (n_completed > 0) | jnp.any(arrived) | ctx.progressed
-        halted = (~jnp.isfinite(t_next)) & ~progressed
-
-        log = st.log
-        if log_rows > 0:
-            slot = jnp.mod(log.cursor, log_rows)
-            write = jnp.mod(st.round, monitor_every) == 0
-
-            def _log_write(operand):
-                log, ext = operand
-                # branch-local ext: subsystem log hooks may update engine
-                # state (e.g. the data subsystem's between-writes WAN
-                # accumulator), so ext rides the cond carry
-                ctx.ext = dict(ext)
-                counts = jax.vmap(
-                    lambda s: jnp.sum((jobs.state == s) & jobs.valid).astype(jnp.int32)
-                )(jnp.arange(N_STATES))
-                q_site = jnp.where(jobs.state == ASSIGNED, jobs.site, S)
-                r_site = jnp.where(jobs.state == RUNNING, jobs.site, S)
-                site_queued = _site_sum(jnp.ones((J,), jnp.int32), q_site, S)
-                site_running = _site_sum(jnp.ones((J,), jnp.int32), r_site, S)
-
-                def wr(buf, val):
-                    return jnp.where(write, buf.at[slot].set(val), buf)
-
-                extra = dict(log.extra)
-                for sub in subsystems:
-                    if sub.log_columns is not None:
-                        for k, v in sub.log_columns(sub, ctx, write).items():
-                            extra[k] = wr(extra[k], v)
-                return EventLog(
-                    time=wr(log.time, clock),
-                    round_idx=wr(log.round_idx, st.round),
-                    counts=wr(log.counts, counts),
-                    n_started=wr(log.n_started, n_started.astype(jnp.int32)),
-                    n_completed=wr(log.n_completed, n_completed.astype(jnp.int32)),
-                    site_free=wr(log.site_free, sites.free_cores),
-                    site_queued=wr(log.site_queued, site_queued),
-                    site_running=wr(log.site_running, site_running),
-                    extra=extra,
-                    cursor=log.cursor + write.astype(jnp.int32),
-                ), ctx.ext
-
-            # the log reductions (two segment sums + a per-state count sweep)
-            # are real per-round work at WLCG scale; behind a scalar cond,
-            # rounds between monitor samples skip them entirely (``wr`` still
-            # selects per lane, so a mixed-write ensemble batch stays exact)
-            log, ctx.ext = jax.lax.cond(
-                _ensemble_any(write), _log_write, lambda op: op, (log, dict(ctx.ext))
+        with jax.named_scope("start"):
+            start_site = jnp.where(started, jobs.site, S)
+            start_sums = _site_sum_stacked(
+                jnp.stack(
+                    [jnp.where(started, jobs.cores, 0), started.astype(jnp.int32)], axis=-1
+                ),
+                start_site,
+                S,
             )
+            used_cores = start_sums[..., 0]
+            used_mem = _site_sum(jnp.where(started, jobs.memory, 0.0), start_site, S)
+            n_start_per_site = start_sums[..., 1]
+            site_c = jnp.minimum(jobs.site, S - 1)
+            share = n_start_per_site[site_c].astype(jnp.float32)
 
-        return EngineState(
-            clock=clock,
-            round=st.round + 1,
-            jobs=jobs,
-            sites=sites,
-            rng=rng,
-            policy_state=pstate,
-            log=log,
-            halted=halted,
-            ext=ctx.ext,
-        )
+            # ---- 5b. service times + subsystem adjustments -------------------
+            ctx.started, ctx.site_c = started, site_c
+            ctx.share, ctx.start_site = share, start_site
+            ctx.t_serv = service_time(jobs, ctx.sites_serv, site_c, share, share)
+            for sub in subsystems:
+                if sub.on_start is not None:
+                    # e.g. workflow output materialization, then replica-aware
+                    # stage-in repricing (DESIGN.md §3/§6) — tuple order matters
+                    with jax.named_scope(sub.name):
+                        sub.on_start(sub, ctx)
+            jobs = ctx.jobs
+            t_serv = ctx.t_serv
+
+            u_fail = jax.random.uniform(k_fail, (J,))
+            # clip (not minimum): unassigned rows carry site == -1, and minimum
+            # would map them to the *last* site's fail rate — masked by `started`
+            # today, but an OOB/NaN-hygiene hazard under refactors
+            will_fail = started & (u_fail < sites.fail_rate[jnp.clip(jobs.site, 0, S - 1)])
+            # a failing attempt dies partway through its service time
+            frac = jax.random.uniform(k_frac, (J,), minval=0.05, maxval=1.0)
+            t_fin = clock + jnp.where(will_fail, t_serv * frac, t_serv)
+
+            jobs = jobs._replace(
+                state=jnp.where(started, RUNNING, jobs.state),
+                t_start=jnp.where(started, clock, jobs.t_start),
+                t_finish=jnp.where(started, t_fin, jobs.t_finish),
+                will_fail=jnp.where(started, will_fail, jobs.will_fail),
+            )
+            sites = sites._replace(
+                free_cores=sites.free_cores - used_cores,
+                free_memory=sites.free_memory - used_mem,
+            )
+            ctx.jobs, ctx.sites = jobs, sites
+
+            pstate = policy.on_step(pstate, jobs, sites, comp, started, clock)
+
+        with jax.named_scope("bookkeeping"):
+            # ---- 6. halt detection & event log -------------------------------
+            n_started = started.sum()
+            n_completed = comp.sum()
+            # subsystem transitions (preemption, cascade rounds) count as progress
+            # so halt detection gives the dispatcher a round to react to them
+            progressed = (n_started > 0) | (n_completed > 0) | jnp.any(arrived) | ctx.progressed
+            halted = (~jnp.isfinite(t_next)) & ~progressed
+
+            log = st.log
+            if log_rows > 0:
+                slot = jnp.mod(log.cursor, log_rows)
+                write = jnp.mod(st.round, monitor_every) == 0
+
+                def _log_write(operand):
+                    log, ext = operand
+                    # branch-local ext: subsystem log hooks may update engine
+                    # state (e.g. the data subsystem's between-writes WAN
+                    # accumulator), so ext rides the cond carry
+                    ctx.ext = dict(ext)
+                    counts = jax.vmap(
+                        lambda s: jnp.sum((jobs.state == s) & jobs.valid).astype(jnp.int32)
+                    )(jnp.arange(N_STATES))
+                    q_site = jnp.where(jobs.state == ASSIGNED, jobs.site, S)
+                    r_site = jnp.where(jobs.state == RUNNING, jobs.site, S)
+                    site_queued = _site_sum(jnp.ones((J,), jnp.int32), q_site, S)
+                    site_running = _site_sum(jnp.ones((J,), jnp.int32), r_site, S)
+
+                    def wr(buf, val):
+                        return jnp.where(write, buf.at[slot].set(val), buf)
+
+                    extra = dict(log.extra)
+                    for sub in subsystems:
+                        if sub.log_columns is not None:
+                            with jax.named_scope(sub.name):
+                                for k, v in sub.log_columns(sub, ctx, write).items():
+                                    extra[k] = wr(extra[k], v)
+                    return EventLog(
+                        time=wr(log.time, clock),
+                        round_idx=wr(log.round_idx, st.round),
+                        counts=wr(log.counts, counts),
+                        n_started=wr(log.n_started, n_started.astype(jnp.int32)),
+                        n_completed=wr(log.n_completed, n_completed.astype(jnp.int32)),
+                        site_free=wr(log.site_free, sites.free_cores),
+                        site_queued=wr(log.site_queued, site_queued),
+                        site_running=wr(log.site_running, site_running),
+                        extra=extra,
+                        cursor=log.cursor + write.astype(jnp.int32),
+                    ), ctx.ext
+
+                # the log reductions (two segment sums + a per-state count sweep)
+                # are real per-round work at WLCG scale; behind a scalar cond,
+                # rounds between monitor samples skip them entirely (``wr`` still
+                # selects per lane, so a mixed-write ensemble batch stays exact)
+                log, ctx.ext = jax.lax.cond(
+                    _ensemble_any(write), _log_write, lambda op: op, (log, dict(ctx.ext))
+                )
+
+            return EngineState(
+                clock=clock,
+                round=st.round + 1,
+                jobs=jobs,
+                sites=sites,
+                rng=rng,
+                policy_state=pstate,
+                log=log,
+                halted=halted,
+                ext=ctx.ext,
+            )
 
     return cond, body
 
@@ -928,12 +954,12 @@ def simulate(
 ) -> SimResult:
     """Run the grid simulation to completion (or ``max_rounds``/``horizon``).
 
-    ``recorder`` (a ``telemetry.TraceRecorder``) makes the run observable at
-    the jit boundary: the call is split into a ``trace_compile`` (cache miss)
-    or ``dispatch`` (cache hit) span plus an ``execute`` span
-    (``block_until_ready``), and rounds-executed / round-budget / early-exit
-    counters are recorded.  ``None`` (the default) adds no host syncs and no
-    overhead — results are bit-for-bit identical either way.
+    The jit call is a ``dispatch`` span (``telemetry.span``) whose
+    ``compiled`` argument says whether it traced and compiled.  A
+    ``recorder`` (a ``telemetry.TraceRecorder``) also gets an ``execute``
+    span (``block_until_ready``), the ``compiles`` count and the
+    ``rounds_executed`` counter.  ``None`` (the default) adds no host sync —
+    results are bit-for-bit identical either way.
 
     ``phase_skip`` (default on) guards the assignment + start phases behind a
     scalar ``lax.cond`` on "any QUEUED/ASSIGNED rows": completion-only rounds
@@ -1015,31 +1041,15 @@ def simulate(
         topk=topk,
         topk_refresh=topk_refresh,
     )
-    if recorder is None:
-        return _simulate(jobs0, sites0, policy, rng, ext0, **kw)
-
-    # flight-recorder path: split the jit call into compile-vs-execute spans
-    # (tracing+compilation is synchronous in the call, execution is async
-    # until block_until_ready) and count rounds against the budget
-    import time as _time
-
-    cache_size = getattr(_simulate, "_cache_size", None)
-    before = cache_size() if cache_size is not None else -1
-    t0 = _time.perf_counter()
-    res = _simulate(jobs0, sites0, policy, rng, ext0, **kw)
-    t_call = _time.perf_counter() - t0
-    compiled = cache_size is not None and cache_size() > before
-    recorder.record("trace_compile" if compiled else "dispatch", t_call)
-    with recorder.span("execute"):
-        jax.block_until_ready(res)
-    rounds = int(res.rounds)
-    recorder.gauge("rounds_executed", rounds)
-    recorder.gauge("round_budget", max_rounds)
-    recorder.gauge("early_exit_rounds", max(max_rounds - rounds, 0))
-    recorder.gauge("n_jobs", int(np.asarray(jobs0.valid).sum()))
-    recorder.gauge("n_sites", sites0.capacity)
-    recorder.note("jit_cache_hit", not compiled)
-    recorder.note("subsystems", [s.name for s in subs])
+    before = _simulate._cache_size()
+    with span("dispatch", recorder) as sp:
+        res = _simulate(jobs0, sites0, policy, rng, ext0, **kw)
+        sp.set(compiled=int(_simulate._cache_size() > before))
+    if recorder is not None:
+        with span("execute", recorder):
+            jax.block_until_ready(res)
+        recorder.gauge("rounds_executed", int(res.rounds))
+        recorder.note("subsystems", [s.name for s in subs])
     return res
 
 
@@ -1107,7 +1117,8 @@ def init_sim(
     )
     if topk is not None:
         topk = min(int(topk), sites0.capacity)
-    st0 = _init_state(jobs0, sites0, policy, rng, ext0, subs, log_rows, topk)
+    with span("init_sim"):
+        st0 = _init_state(jobs0, sites0, policy, rng, ext0, subs, log_rows, topk)
     statics = (max_rounds, log_rows, max_retries, monitor_every, quantum, phase_skip,
                topk, topk_refresh)
     return SimHandle(state=st0, policy=policy, subsystems=subs, statics=statics)
@@ -1139,15 +1150,25 @@ def _segment_fn(policy, subsystems: tuple, statics: tuple):
     return jax.jit(run)
 
 
-def advance_sim(handle: SimHandle, horizon: float = float("inf")) -> SimHandle:
+def advance_sim(handle: SimHandle, horizon: float = float("inf"), *,
+                recorder=None) -> SimHandle:
     """Run rounds until the clock passes ``horizon`` (or the run drains).
 
     Because ``cond`` checks the clock *before* each round, resuming with a
     larger horizon continues the identical round sequence a single
     ``simulate`` call would have executed — segmentation changes where the
-    loop pauses, never what it computes (property-tested bit-for-bit)."""
-    run = _segment_fn(handle.policy, tuple(handle.subsystems), handle.statics)
-    return handle._replace(state=run(handle.state, jnp.float32(horizon)))
+    loop pauses, never what it computes (property-tested bit-for-bit).
+
+    The call, from entry to return, is an ``advance_sim`` span
+    (``telemetry.span``; timed into ``recorder`` when given) whose
+    ``compiled`` argument says whether this call traced or compiled the
+    segment program."""
+    with span("advance_sim", recorder) as sp:
+        run = _segment_fn(handle.policy, tuple(handle.subsystems), handle.statics)
+        before = run._cache_size()  # 0 for a segment program made just now
+        state = run(handle.state, jnp.float32(horizon))
+        sp.set(compiled=int(run._cache_size() > before))
+    return handle._replace(state=state)
 
 
 def sim_active(handle: SimHandle) -> bool:
@@ -1165,7 +1186,8 @@ def sim_active(handle: SimHandle) -> bool:
 
 def finish_sim(handle: SimHandle) -> SimResult:
     """Run end-of-run hooks on a (drained or abandoned) handle."""
-    return _finalize(handle.state, handle.policy, tuple(handle.subsystems))
+    with span("finish_sim"):
+        return _finalize(handle.state, handle.policy, tuple(handle.subsystems))
 
 
 # --------------------------------------------------------------------------

@@ -129,14 +129,16 @@ def watch(
     ``python -m repro.monitor --follow run.ndjson``) and/or renders to
     ``out``.  The stream carries a ``run_meta`` record first (site cores and
     names — what a renderer needs) and an ``end`` record last.  Pass a
-    ``telemetry.TraceRecorder`` to time the segments; remaining ``**kw``
-    (``log_rows``, subsystems, ...) forward to the engine.
+    ``telemetry.TraceRecorder`` to time the segments, each from its dispatch
+    through the host read of its frame; remaining ``**kw`` (``log_rows``,
+    subsystems, ...) forward to the engine.
     """
-    from .engine import advance_sim, finish_sim, init_sim, sim_active
-    from .telemetry import maybe
+    import jax
 
-    rec = maybe(recorder)
-    with rec.span("watch_init"):
+    from .engine import advance_sim, finish_sim, init_sim, sim_active
+    from .telemetry import span
+
+    with span("watch_init", recorder):
         handle = init_sim(jobs0, sites0, policy, rng, **kw)
     hz = None if horizon is None or not np.isfinite(horizon) else float(horizon)
     if segment is not None:
@@ -166,9 +168,10 @@ def watch(
     while sim_active(handle) and n_seg < max_segments:
         t_edge += dt
         at_end = hz is not None and t_edge >= hz
-        with rec.span("watch_segment"):
-            handle = advance_sim(handle, hz if at_end else t_edge)
-        frame = state_frame(handle)
+        # the segment runs until its frame is read back: the span covers both
+        with span("watch_segment", recorder):
+            handle = advance_sim(handle, hz if at_end else t_edge, recorder=recorder)
+            frame = state_frame(handle)
         if sink is not None:
             sink.emit({"type": "frame", **frame})
         if render:
@@ -178,12 +181,14 @@ def watch(
             break
     if hz is None and sim_active(handle):
         # segment budget exhausted on an open-horizon run: drain to the end
-        with rec.span("watch_segment"):
-            handle = advance_sim(handle)
-    with rec.span("watch_finalize"):
+        with span("watch_segment", recorder):
+            handle = advance_sim(handle, recorder=recorder)
+            jax.block_until_ready(handle.state)
+    with span("watch_finalize", recorder):
         res = finish_sim(handle)
-    rec.gauge("watch_segments", n_seg)
-    rec.gauge("rounds_executed", int(res.rounds))
+    if recorder is not None:
+        recorder.gauge("watch_segments", n_seg)
+        recorder.gauge("rounds_executed", int(res.rounds))
     if sink is not None:
         sink.emit(
             dict(

@@ -5,11 +5,14 @@ event-level run datasets (CGSim §4.3.3, Table 1); what it never records is
 why a run was fast or slow.  This module is the observability substrate for
 the whole harness (DESIGN.md §9):
 
+- ``span`` — the program's one span primitive: a profiler annotation on the
+  device trace's clock (``init_sim``, ``advance_sim``, ``finish_sim``, the
+  ``simulate`` and ensemble entry points), also timed into a
+  ``TraceRecorder`` when one is passed.  The round loop's device phases are
+  ``jax.named_scope``s instead (``engine.PHASES``).
 - ``TraceRecorder`` — a host-side span/counter recorder wrapped around the
-  jit boundary (``with rec.span("execute"): ...``).  Spans cost two
-  ``perf_counter`` calls and a dict update; every instrumentation site in the
-  engine is guarded by ``recorder is not None``, so a recorder-less run pays
-  nothing.
+  jit boundary (``with rec.span("execute"): ...``): per-name seconds and
+  counts, counters, notes.
 - ``Sink`` — a tiny streaming-record protocol (``emit(dict)``/``close()``)
   with NDJSON-file, in-memory, and callback implementations.  Monitor frames,
   telemetry spans, and event rows all stream through sinks, so export memory
@@ -33,6 +36,7 @@ import time
 from typing import Any, Callable, Protocol, runtime_checkable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 MANIFEST_SCHEMA = "cgsim.run_manifest/v1"
 MANIFEST_SUFFIX = ".manifest.json"
@@ -166,37 +170,51 @@ def iter_ndjson(source, *, follow: bool = False, poll_s: float = 0.2,
 
 
 # --------------------------------------------------------------------------
-# TraceRecorder: spans + counters around the jit boundary
+# spans, and the TraceRecorder: spans + counters around the jit boundary
 # --------------------------------------------------------------------------
 
 
-class _Span:
-    __slots__ = ("_rec", "_name", "_t0")
+class Span:
+    """One named span of the program (see ``span``)."""
 
-    def __init__(self, rec: "TraceRecorder", name: str):
-        self._rec = rec
-        self._name = name
+    __slots__ = ("name", "args", "_rec", "_ann", "_t0")
+
+    def __init__(self, name: str, recorder=None, **args):
+        self.name = name
+        self.args = args
+        self._rec = recorder
 
     def __enter__(self):
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
+    def set(self, **args) -> None:
+        """Add arguments known only inside the span, such as ``compiled``."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
     def __exit__(self, *exc):
-        self._rec.record(self._name, time.perf_counter() - self._t0)
+        seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        if self._rec is not None:
+            self._rec.record(self.name, seconds)
+            if self.args.get("compiled"):
+                self._rec.count("compiles")
         return False
 
 
-class _NullSpan:
-    __slots__ = ()
+def span(name: str, recorder=None, **args) -> Span:
+    """The program's one way to open a span: ``with span("advance_sim"):``.
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+    It always opens a ``jax.profiler.TraceAnnotation`` named ``name`` with
+    ``args`` as its arguments, so under an active profiler the span lands in
+    the trace's host plane, on the device trace's clock; with no profiler it
+    costs about a microsecond.  With a ``recorder`` the span's wall-clock
+    seconds are also added to it under ``name``, and a span that ends with
+    ``compiled=1`` counts one of the recorder's ``compiles``."""
+    return Span(name, recorder, **args)
 
 
 class TraceRecorder:
@@ -214,8 +232,8 @@ class TraceRecorder:
         self.notes: dict[str, Any] = {}
         self._sink = sink
 
-    def span(self, name: str) -> _Span:
-        return _Span(self, name)
+    def span(self, name: str, **args) -> Span:
+        return span(name, self, **args)
 
     def record(self, name: str, seconds: float) -> None:
         e = self.spans.get(name)
@@ -253,15 +271,15 @@ class TraceRecorder:
 
 
 class NullRecorder:
-    """API-compatible no-op recorder; ``span`` returns a shared no-op
-    context manager, so instrumentation sites cost an attribute lookup."""
+    """API-compatible no-op recorder; its spans are profiler annotations
+    only."""
 
     spans: dict = {}
     counters: dict = {}
     notes: dict = {}
 
-    def span(self, name: str):
-        return _NULL_SPAN
+    def span(self, name: str, **args) -> Span:
+        return span(name, **args)
 
     def record(self, name: str, seconds: float) -> None:
         pass
@@ -280,14 +298,6 @@ class NullRecorder:
 
     def summary(self) -> dict:
         return dict(spans={}, counters={}, notes={})
-
-
-NULL_RECORDER = NullRecorder()
-
-
-def maybe(recorder) -> TraceRecorder | NullRecorder:
-    """Normalize an optional recorder: ``None`` becomes the shared no-op."""
-    return NULL_RECORDER if recorder is None else recorder
 
 
 # --------------------------------------------------------------------------

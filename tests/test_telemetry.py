@@ -142,18 +142,50 @@ def test_simulate_with_recorder_matches_and_records():
     for a, b in zip(jax.tree.leaves(base), jax.tree.leaves(res)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     s = rec.summary()
-    # one call = either a fresh compile or a cache hit, never both
-    assert ("trace_compile" in s["spans"]) != ("dispatch" in s["spans"])
-    assert "execute" in s["spans"]
+    # one dispatch span, then the wait for the result
+    assert s["spans"]["dispatch"]["count"] == 1
+    assert s["spans"]["execute"]["count"] == 1
     assert s["counters"]["rounds_executed"] == int(base.rounds)
-    assert s["counters"]["early_exit_rounds"] == (
-        s["counters"]["round_budget"] - int(base.rounds)
-    )
-    assert s["counters"]["n_jobs"] == 60
-    # warm second call must be a dispatch, not a recompile
+    # the first call above compiled: this one found the program
+    assert "compiles" not in s["counters"]
+    assert s["notes"]["subsystems"] == []
+    # a new static configuration compiles once, then is found again
     rec2 = TraceRecorder()
-    simulate(jobs, sites, pol, key, recorder=rec2)
-    assert "dispatch" in rec2.summary()["spans"]
+    simulate(jobs, sites, pol, key, max_rounds=54_321, recorder=rec2)
+    simulate(jobs, sites, pol, key, max_rounds=54_321, recorder=rec2)
+    s2 = rec2.summary()
+    assert s2["counters"]["compiles"] == 1
+    assert s2["spans"]["dispatch"]["count"] == 2
+
+
+def test_program_spans_land_in_the_profiler_trace(tmp_path):
+    """The segmented API's spans are profiler annotations in the trace's
+    host plane; ``advance_sim`` says whether the call compiled."""
+    from jax.profiler import ProfileData
+
+    from repro.core import advance_sim, finish_sim, init_sim
+
+    jobs, sites, pol, key = tiny_scenario()
+    rec = TraceRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        # a static configuration no other test uses: the first call compiles
+        h = init_sim(jobs, sites, pol, key, max_rounds=43_210)
+        h = advance_sim(h, 600.0, recorder=rec)
+        h = advance_sim(h, recorder=rec)
+        finish_sim(h)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    host = [(e.name, dict(e.stats)) for p in ProfileData.from_file(str(path)).planes
+            if p.name.startswith("/host") for line in p.lines for e in line.events
+            if e.name in ("init_sim", "advance_sim", "finish_sim")]
+    assert [n for n, _ in host].count("init_sim") == 1
+    assert [n for n, _ in host].count("finish_sim") == 1
+    assert [a["compiled"] for n, a in host if n == "advance_sim"] == [1, 0]
+    s = rec.summary()
+    assert s["spans"]["advance_sim"]["count"] == 2
+    assert s["counters"]["compiles"] == 1
 
 
 # --------------------------------------------------------------------------
@@ -201,7 +233,13 @@ def test_watch_ndjson_stream_renders_via_follow(tmp_path):
     text = out.getvalue()
     assert "cores" in text and "end:" in text
     assert f"rounds={int(res.rounds)}" in text
-    assert rec.summary()["counters"]["watch_segments"] > 0
+    s = rec.summary()
+    assert s["counters"]["watch_segments"] > 0
+    assert s["counters"]["rounds_executed"] == int(res.rounds)
+    # one advance_sim span per segment, each inside its watch_segment span
+    assert s["spans"]["advance_sim"]["count"] == s["spans"]["watch_segment"]["count"]
+    assert s["spans"]["advance_sim"]["total_s"] <= s["spans"]["watch_segment"]["total_s"]
+    assert s["counters"].get("compiles", 0) <= 1
     assert read_manifest(path)["scenario"]["n_jobs"] == 60
 
 
@@ -249,11 +287,11 @@ def test_lane_occupancy_idle_lane():
     s = occ["summary"]
     assert s["n_lanes"] == 2
     assert 0.0 < s["lockstep_waste_frac"] < 1.0
-    # the sharded-run recorder saw the same lanes
-    c = rec.summary()["counters"]
-    assert c["lanes"] == 2
-    assert c["lane_rounds_max"] == lanes[1]["rounds"]
-    assert "ensemble_run" in rec.summary()["spans"]
+    # the sharded-run recorder timed the stacking and the run
+    spans = rec.summary()["spans"]
+    assert "ensemble_stack" not in spans  # the scenarios came stacked
+    assert spans["ensemble_run"]["count"] == 1
+    assert rec.summary()["counters"] == {}
 
 
 def test_padding_stats_bucketed_beats_flat():
