@@ -19,6 +19,15 @@ pure functions with the same extension-point shape as ``Policy``:
 
 All fields are jit-traceable, so ``engine.simulate`` with a DataPolicy keeps
 vmapping under ``simulate_ensemble``.
+
+``select_source`` and ``should_cache`` are row-wise: row j of the result
+depends on row j of ``jobs`` and ``dst`` (and on the shared catalog, network
+and state), and the length of the result is ``jobs.capacity``.  The stage-in
+hook runs them on the rows that start this round only: on a gathered row set
+that holds every starting row, padded with other rows whose results are
+ignored, or on all J rows when a round starts more than ``COMPACT_ROWS``
+jobs (the wide fallback).  ``on_step`` always sees J-wide ``jobs``,
+``started`` and ``xfer``.
 """
 from __future__ import annotations
 
@@ -84,6 +93,10 @@ def make_data_policy(
 # the ext slot carries (network, catalog, policy state, WAN-ingress accum).
 # --------------------------------------------------------------------------
 
+# Starting rows the stage-in hook prices per round on its compact path.  A
+# round that starts more takes the J-wide path.
+COMPACT_ROWS = 64
+
 
 class DataExt(NamedTuple):
     """The data subsystem's ``EngineState.ext["data"]`` slot."""
@@ -92,6 +105,7 @@ class DataExt(NamedTuple):
     replicas: ReplicaState
     state: object        # DataPolicy-defined pytree
     net_acc: jax.Array   # f32[S] WAN bytes staged since the last log write
+    wide_rounds: jax.Array  # i32[] rounds whose stage-in ran on all J rows
 
 
 def _data_init(sub, state0, jobs, sites):
@@ -102,6 +116,7 @@ def _data_init(sub, state0, jobs, sites):
         replicas=replicas,
         state=dstate,
         net_acc=jnp.zeros((sites.capacity,), jnp.float32),
+        wide_rounds=jnp.zeros((), jnp.int32),
     )
 
 
@@ -109,17 +124,70 @@ def _data_on_start(sub, ctx):
     """Replica-aware stage-in (engine step 5b, DESIGN.md §3): dataset jobs
     swap the flat latency+stage-in terms for a WAN transfer from the
     policy-selected replica, with catalog bookkeeping (LRU touch,
-    cache-on-read insertion, hit/transfer counters)."""
+    cache-on-read insertion, hit/transfer counters).
+
+    Only the rows that start this round are priced: ``engine._first_rows``
+    finds up to ``COMPACT_ROWS`` of them, and the results go back into the
+    J-wide columns by scatters of that many rows.  A round that starts more
+    jobs (in any lane of an ensemble) runs the same code on all J rows, and
+    so does every round of a run with the transfer queues, which take J-wide
+    arrays; the ext slot's ``wide_rounds`` counts those rounds."""
+    from .engine import _ensemble_any, _first_rows
+
+    policy = sub.config
+    k = min(ctx.J, COMPACT_ROWS)
+    dext = ctx.ext["data"]
+    if "transfers" in ctx.ext:
+        wide = jnp.bool_(True)
+        out = _stage_in(policy, ctx, dext, None)
+    else:
+        wide = _ensemble_any(ctx.started.sum() > k)
+        out = jax.lax.cond(
+            wide,
+            lambda: _stage_in(policy, ctx, dext, None),
+            lambda: _stage_in(policy, ctx, dext, _first_rows(ctx.started, k)),
+        )
+    ctx.t_serv, ctx.jobs, dext, xfer = out
+    dstate = policy.on_step(dext.state, ctx.jobs, dext.replicas, ctx.started, xfer, ctx.clock)
+    ctx.ext["data"] = dext._replace(
+        state=dstate, wide_rounds=dext.wide_rounds + wide.astype(jnp.int32)
+    )
+
+
+def _stage_in(policy, ctx, dext: DataExt, idx):
+    """The stage-in of this round's starting rows among ``idx``, ascending
+    row indices padded with J (``None``: all J rows).  The row math runs on
+    those rows only; returns ``(t_serv, jobs, dext, xfer)`` with the J-wide
+    columns updated on them.  The engine reads ``t_serv`` only where
+    ``started``, so on the compact path the other rows read 0, and the
+    engine's J-wide flat-link ``t_serv`` goes unused."""
     from .engine import _site_sum, service_time, stage_in_time
     from .network import shared_transfer_times
     from .replicas import insert_replicas, touch
 
-    policy = sub.config
-    dext = ctx.ext["data"]
     network, rep, dstate = dext.network, dext.replicas, dext.state
-    jobs, sites, S = ctx.jobs, ctx.sites, ctx.S
-    started, site_c, share, start_site = ctx.started, ctx.site_c, ctx.share, ctx.start_site
-    clock = ctx.clock
+    sites, S, J, clock = ctx.sites, ctx.S, ctx.J, ctx.clock
+    if idx is None:
+        def rows(col):
+            return col
+
+        def put(col, val):
+            return val
+
+        started = ctx.started
+    else:
+        safe = jnp.minimum(idx, J - 1)
+
+        def rows(col):
+            return col[safe]
+
+        def put(col, val):
+            return col.at[idx].set(val, mode="drop")
+
+        started = idx < J
+    jobs = jax.tree.map(rows, ctx.jobs)
+    site_c, start_site = rows(ctx.site_c), rows(ctx.start_site)
+    share = ctx.start_count[site_c].astype(jnp.float32)
 
     has_ds = jobs.dataset >= 0
     # only flat-link stage-ins contend for the site ingress link; dataset
@@ -142,10 +210,10 @@ def _data_on_start(sub, ctx):
     # priced instantly — the staging gate and landing happen in transfers.py
     defer = "transfers" in ctx.ext
     if defer:
-        ctx.t_serv = jnp.where(has_ds, t_serv - in_flat, t_serv)
+        t_start = jnp.where(has_ds, t_serv - in_flat, t_serv)
     else:
         t_net, _ = shared_transfer_times(network, src_c, site_c, ds_bytes, xfer)
-        ctx.t_serv = jnp.where(has_ds, t_serv - in_flat + t_net, t_serv)
+        t_start = jnp.where(has_ds, t_serv - in_flat + t_net, t_serv)
     # catalog bookkeeping: touch LRU clocks, cache-on-read insertion
     rep = touch(rep, jobs.dataset, src_c, xfer, clock)
     rep = touch(rep, jobs.dataset, site_c, read & local, clock)
@@ -168,19 +236,20 @@ def _data_on_start(sub, ctx):
         rep = insert_replicas(rep, jobs.dataset, site_c, want_cache, clock)
         rep = rep._replace(
             n_transfers=rep.n_transfers + xfer.sum().astype(jnp.int32),
-            bytes_moved=rep.bytes_moved + moved.sum(),
+            # summed over the J-wide column, so the float adds group alike
+            # on both paths
+            bytes_moved=rep.bytes_moved + put(jnp.zeros((J,), jnp.float32), moved).sum(),
         )
         net_in_now = net_in_now + _site_sum(moved, jnp.where(xfer, jobs.site, S), S)
         t_net_col = t_net
-    ctx.jobs = jobs._replace(
-        xfer_src=jnp.where(read, src_c, jobs.xfer_src),
-        xfer_bytes=jnp.where(read, moved, jobs.xfer_bytes),
-        xfer_time=jnp.where(read, t_net_col, jobs.xfer_time),
+    jobs = ctx.jobs._replace(
+        xfer_src=put(ctx.jobs.xfer_src, jnp.where(read, src_c, jobs.xfer_src)),
+        xfer_bytes=put(ctx.jobs.xfer_bytes, jnp.where(read, moved, jobs.xfer_bytes)),
+        xfer_time=put(ctx.jobs.xfer_time, jnp.where(read, t_net_col, jobs.xfer_time)),
     )
-    dstate = policy.on_step(dstate, ctx.jobs, rep, started, xfer, clock)
-    ctx.ext["data"] = DataExt(
-        network=network, replicas=rep, state=dstate, net_acc=net_in_now
-    )
+    dext = dext._replace(replicas=rep, net_acc=net_in_now)
+    t_serv = put(jnp.zeros((J,), jnp.float32), t_start)
+    return t_serv, jobs, dext, put(jnp.zeros((J,), bool), xfer)
 
 
 def land_deferred(dext: DataExt, jobs, done, cache, clock, S):
@@ -218,7 +287,11 @@ def _data_log_columns(sub, ctx, write):
 def _data_finalize(sub, dext: DataExt, jobs, sites, clock):
     dstate = sub.config.on_end(dext.state, jobs, dext.replicas, clock)
     dext = dext._replace(state=dstate)
-    return dext, {"replicas": dext.replicas, "data_state": dstate}
+    return dext, {
+        "replicas": dext.replicas,
+        "data_state": dstate,
+        "data_wide_rounds": dext.wide_rounds,
+    }
 
 
 def data_subsystem(policy: DataPolicy) -> "Subsystem":

@@ -348,6 +348,20 @@ def _ensemble_any_batched(axis_size, in_batched, pred):
     return jnp.any(pred, axis=0) if in_batched[0] else pred, False
 
 
+def _first_rows(mask: jax.Array, k: int) -> jax.Array:
+    """``i32[k]``: the ascending indices of the first ``k`` true rows of
+    ``mask``, padded with ``J`` (one past the last row).
+
+    Row compaction for hooks that only need the few rows a round touches.
+    Built from a prefix count and a ``[k, J]`` compare-and-count, with no
+    gather or scatter over J: the ``r``-th true row sits at the number of
+    rows whose running count is still ``<= r``, which is ``J`` when fewer
+    than ``r + 1`` rows are true."""
+    count = jnp.cumsum(mask.astype(jnp.int32))
+    r = jnp.arange(k, dtype=jnp.int32)
+    return jnp.sum(count[None, :] <= r[:, None], axis=-1, dtype=jnp.int32)
+
+
 def _segment_exclusive_base(values: jax.Array, seg_ids: jax.Array, num_segments: int):
     """For values sorted by seg_ids: per-element cumulative sum *within* its segment."""
     total_cum = jnp.cumsum(values)
@@ -742,7 +756,7 @@ def _round_fns(
 
             # ---- 5b. service times + subsystem adjustments -------------------
             ctx.started, ctx.site_c = started, site_c
-            ctx.share, ctx.start_site = share, start_site
+            ctx.start_site, ctx.start_count = start_site, n_start_per_site
             ctx.t_serv = service_time(jobs, ctx.sites_serv, site_c, share, share)
             for sub in subsystems:
                 if sub.on_start is not None:
@@ -957,9 +971,11 @@ def simulate(
     The jit call is a ``dispatch`` span (``telemetry.span``) whose
     ``compiled`` argument says whether it traced and compiled.  A
     ``recorder`` (a ``telemetry.TraceRecorder``) also gets an ``execute``
-    span (``block_until_ready``), the ``compiles`` count and the
-    ``rounds_executed`` counter.  ``None`` (the default) adds no host sync —
-    results are bit-for-bit identical either way.
+    span (``block_until_ready``), the ``compiles`` count, the
+    ``rounds_executed`` counter and, with a data policy, the
+    ``data_wide_rounds`` counter (rounds whose stage-in ran on all J rows).
+    ``None`` (the default) adds no host sync — results are bit-for-bit
+    identical either way.
 
     ``phase_skip`` (default on) guards the assignment + start phases behind a
     scalar ``lax.cond`` on "any QUEUED/ASSIGNED rows": completion-only rounds
@@ -1049,6 +1065,8 @@ def simulate(
         with span("execute", recorder):
             jax.block_until_ready(res)
         recorder.gauge("rounds_executed", int(res.rounds))
+        if res.data_wide_rounds is not None:
+            recorder.gauge("data_wide_rounds", int(res.data_wide_rounds))
         recorder.note("subsystems", [s.name for s in subs])
     return res
 

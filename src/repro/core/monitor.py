@@ -189,6 +189,8 @@ def watch(
     if recorder is not None:
         recorder.gauge("watch_segments", n_seg)
         recorder.gauge("rounds_executed", int(res.rounds))
+        if res.data_wide_rounds is not None:
+            recorder.gauge("data_wide_rounds", int(res.data_wide_rounds))
     if sink is not None:
         sink.emit(
             dict(

@@ -105,8 +105,11 @@ class RoundCtx:
                          (DESIGN.md §12)
       start_cores        i32[S] cores the start phase may claim this round
       sites_serv         SiteState used for service-time pricing (speed mods)
-      started, site_c, share, start_site   start-phase masks (engine, step 5)
-      t_serv             f32[J] service time of starting jobs (override/adjust)
+      started, site_c, start_site   start-phase masks (engine, step 5)
+      start_count        i32[S] jobs starting at each site (they share its
+                         stage links: a row's share is ``start_count[site_c]``)
+      t_serv             f32[J] service time of starting jobs (override/adjust;
+                         only the ``started`` rows are read)
       progressed         OR in a bool[] if your transitions made progress
       scratch            per-round dict for passing values between your hooks
       max_retries, S, J  static knobs
@@ -137,8 +140,8 @@ class RoundCtx:
         self.sites_serv = None
         self.started = None
         self.site_c = None
-        self.share = None
         self.start_site = None
+        self.start_count = None
         self.t_serv = None
         self.progressed = False
         self.scratch = {}

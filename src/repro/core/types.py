@@ -141,6 +141,7 @@ class SimResult(NamedTuple):
     policy_state: object
     replicas: object = None     # final ReplicaState (None without a DataPolicy)
     data_state: object = ()
+    data_wide_rounds: object = None  # i32[] rounds whose stage-in ran on all J rows
     avail: object = None        # final AvailabilityState (None without availability)
     wf: object = None           # final WorkflowState (None without a workflow DAG)
     ext: object = None          # {name: final state} for every attached subsystem
