@@ -47,6 +47,7 @@ from .types import (
     N_STATES,
     PENDING,
     QUEUED,
+    RESULT_COUNTERS,
     RUNNING,
     EngineState,
     EventLog,
@@ -973,7 +974,9 @@ def simulate(
     ``recorder`` (a ``telemetry.TraceRecorder``) also gets an ``execute``
     span (``block_until_ready``), the ``compiles`` count, the
     ``rounds_executed`` counter and, with a data policy, the
-    ``data_wide_rounds`` counter (rounds whose stage-in ran on all J rows).
+    ``data_wide_rounds`` counter (rounds whose stage-in ran on all J rows);
+    with transfer queues, ``xfer_enqueued``, ``xfer_landed`` and
+    ``xfer_waited`` (transfers admitted after waiting for a link slot).
     ``None`` (the default) adds no host sync — results are bit-for-bit
     identical either way.
 
@@ -1065,8 +1068,9 @@ def simulate(
         with span("execute", recorder):
             jax.block_until_ready(res)
         recorder.gauge("rounds_executed", int(res.rounds))
-        if res.data_wide_rounds is not None:
-            recorder.gauge("data_wide_rounds", int(res.data_wide_rounds))
+        for name in RESULT_COUNTERS:
+            if getattr(res, name) is not None:
+                recorder.gauge(name, int(getattr(res, name)))
         recorder.note("subsystems", [s.name for s in subs])
     return res
 

@@ -419,14 +419,14 @@ def _fl_on_completions(sub, ctx):
         # backoff expired: the full transfer restarts as a fresh ledger
         # attempt on the same link (resid/cache/link survive in the
         # transfer rows; rem resets to the full size)
-        ts, _ = _enqueue(ts, due, ts.link, jobs.xfer_bytes, ts.resid, ts.cache, clock)
+        ts = _enqueue(ts, due, ts.link, jobs.xfer_bytes, ts.resid, ts.cache, clock)
         fs = fs._replace(
             retry_at=jnp.where(due | orphan, INF, fs.retry_at),
             attempt=jnp.where(orphan, 0, fs.attempt),
             n_xfer_retry=fs.n_xfer_retry + due.sum().astype(jnp.int32),
         )
         if dext is not None:
-            ts = _admit(ts, clock)
+            ts, _ = _admit(ts, clock)
             ts = _reprice(ts, dext.network.bw.reshape(L), clock)
         ctx.ext["transfers"] = ts
         ctx.progressed = ctx.progressed | due.any() | tr.any()

@@ -19,7 +19,7 @@ import sys
 import numpy as np
 
 from .events import log_frames
-from .types import ASSIGNED, RUNNING, SimResult, STATE_NAMES
+from .types import ASSIGNED, RESULT_COUNTERS, RUNNING, SimResult, STATE_NAMES
 
 BAR = " ▁▂▃▄▅▆▇█"
 
@@ -189,8 +189,9 @@ def watch(
     if recorder is not None:
         recorder.gauge("watch_segments", n_seg)
         recorder.gauge("rounds_executed", int(res.rounds))
-        if res.data_wide_rounds is not None:
-            recorder.gauge("data_wide_rounds", int(res.data_wide_rounds))
+        for name in RESULT_COUNTERS:
+            if getattr(res, name) is not None:
+                recorder.gauge(name, int(getattr(res, name)))
     if sink is not None:
         sink.emit(
             dict(

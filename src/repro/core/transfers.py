@@ -10,9 +10,10 @@ bandwidth — dominate data-access latency at scale (arxiv 2403.14903,
 
 This module models that as a pure additive :class:`~.subsystems.Subsystem`:
 
-- Each directed link ``src -> dst`` (flattened id ``src * S + dst``) owns a
-  fixed-shape FIFO ring of job ids (``i32[L, Q]``), an ``active`` counter,
-  and a ``cap`` (``max_active``).
+- Each directed link ``src -> dst`` (flattened id ``src * S + dst``) has an
+  ``active`` counter and a ``cap`` (``max_active``).  The queue itself is
+  per-job state: a queued row keeps its link and its enqueue ticket, and a
+  link's FIFO is its queued rows in ticket order.
 - When a dataset job starts on a WAN read, the data subsystem *defers* the
   transfer here instead of pricing it: the job enters a **staging gate** —
   it is RUNNING with ``t_finish = inf`` so it is excluded from the engine's
@@ -26,15 +27,12 @@ This module models that as a pure additive :class:`~.subsystems.Subsystem`:
   into ``t_finish``, cache-on-read replicas materialize at the destination,
   and the freed slot admits the next queued transfer.
 
-Fixed shapes and masked algebra throughout: the subsystem jit/vmaps under
-``simulate_many`` / ``simulate_many_sharded``, and ``transfers=None`` is a
-bit-for-bit no-op (static specialization removes every trace).
-
-Preempted staging jobs (availability outages) are handled with stamped
-tickets: cancelling a queued transfer leaves a tombstone in the ring that is
-garbage-collected for free when it reaches the head, and a ticket mismatch
-keeps a re-enqueued retry of the same job from being confused with its stale
-entry.
+Every array is J-wide or L-wide (``L = S * S``): at S=300 and J=100k the
+state is a few MB.  Fixed shapes and masked algebra throughout: the
+subsystem jit/vmaps under ``simulate_many`` / ``simulate_many_sharded``, and
+``transfers=None`` is a bit-for-bit no-op (static specialization removes
+every trace).  A preempted staging job cancels its transfer, which leaves
+nothing behind in the queue.
 """
 from __future__ import annotations
 
@@ -56,15 +54,11 @@ T_IDLE, T_QUEUED, T_ACTIVE = 0, 1, 2
 class TransferState(NamedTuple):
     """The transfer subsystem's ``EngineState.ext["transfers"]`` slot.
 
-    Link axis ``L = S * S`` over flattened directed links; ring axis ``Q``
-    (queue slots per link); transfer axis = the job capacity ``J``.
+    Link axis ``L = S * S`` over flattened directed links; transfer axis =
+    the job capacity ``J``.
     """
 
-    # per-link FIFO rings
-    queue: jax.Array    # i32[L, Q] job ids (-1 = empty slot)
-    tickets: jax.Array  # i32[L, Q] enqueue ticket stamped into each slot
-    head: jax.Array     # i32[L] ring read position
-    qlen: jax.Array     # i32[L] occupied slots from head (incl. tombstones)
+    # per-link
     active: jax.Array   # i32[L] transfers currently moving bytes
     cap: jax.Array      # i32[L] max_active per link (FTS channel limit)
     # per-transfer rows (indexed by job id)
@@ -77,13 +71,13 @@ class TransferState(NamedTuple):
     #                     stage-out + WAN latency), priced into t_finish at release
     enq_t: jax.Array    # f32[J] enqueue clock
     act_t: jax.Array    # f32[J] activation clock
-    ticket: jax.Array   # i32[J] current enqueue ticket (-1 = none)
+    ticket: jax.Array   # i32[J] enqueue ticket: the link's FIFO order (-1 = none)
     cache: jax.Array    # bool[J] materialize a replica at the dst on landing
     # conservation counters (every enqueue terminates as done or cancelled)
     n_enq: jax.Array       # i32 total transfers enqueued (also ticket counter)
-    n_done: jax.Array      # i32 transfers completed
+    n_done: jax.Array      # i32 transfers landed
     n_cancel: jax.Array    # i32 transfers cancelled (staging job preempted)
-    n_overflow: jax.Array  # i32 ring-full enqueues admitted past the cap
+    n_waited: jax.Array    # i32 transfers admitted after waiting for a slot (act_t > enq_t)
     bytes_enq: jax.Array     # f32 bytes enqueued
     bytes_done: jax.Array    # f32 bytes of completed transfers (full size)
     bytes_cancel: jax.Array  # f32 bytes of cancelled transfers (full size)
@@ -95,27 +89,18 @@ def make_transfers(
     *,
     max_active: int = 4,
     caps=None,
-    queue_slots: int | None = None,
 ) -> TransferState:
     """Build an empty transfer-queue state.
 
     ``n_sites`` also accepts a ``NetworkState``/``SiteState``; ``job_capacity``
     also accepts a ``JobsState``.  ``max_active`` is the default per-link
     concurrency cap, refined by ``caps`` (a ``{(src, dst): cap}`` mapping or a
-    full ``[S, S]`` matrix — see :func:`~.network.link_caps`).  ``queue_slots``
-    defaults to the job capacity, which can never overflow since each job
-    holds at most one in-flight transfer.
+    full ``[S, S]`` matrix — see :func:`~.network.link_caps`).
     """
     S = getattr(n_sites, "n_sites", None) or getattr(n_sites, "capacity", None) or int(n_sites)
     J = getattr(job_capacity, "capacity", None) or int(job_capacity)
     L = S * S
-    Q = int(queue_slots) if queue_slots is not None else J
-    Q = max(Q, 1)
     return TransferState(
-        queue=jnp.full((L, Q), -1, jnp.int32),
-        tickets=jnp.full((L, Q), -1, jnp.int32),
-        head=jnp.zeros((L,), jnp.int32),
-        qlen=jnp.zeros((L,), jnp.int32),
         active=jnp.zeros((L,), jnp.int32),
         cap=link_caps(S, max_active, caps),
         stat=jnp.zeros((J,), jnp.int32),
@@ -130,7 +115,7 @@ def make_transfers(
         n_enq=jnp.int32(0),
         n_done=jnp.int32(0),
         n_cancel=jnp.int32(0),
-        n_overflow=jnp.int32(0),
+        n_waited=jnp.int32(0),
         bytes_enq=jnp.float32(0.0),
         bytes_done=jnp.float32(0.0),
         bytes_cancel=jnp.float32(0.0),
@@ -138,7 +123,7 @@ def make_transfers(
 
 
 # --------------------------------------------------------------------------
-# queue mechanics (all fixed-shape [L, Q] / [J] masked algebra)
+# queue mechanics (fixed-shape [J] / [L] masked algebra)
 # --------------------------------------------------------------------------
 
 
@@ -151,43 +136,18 @@ def _link_count(mask, link, L):
 
 
 def _enqueue(ts: TransferState, want, link, nbytes, resid, cache, clock):
-    """Append the ``want`` rows to their links' FIFO rings.
+    """Queue the ``want`` rows on their links.
 
-    Same-round enqueuers on one link are ordered by job id — they start at
-    the same instant, and the id tiebreak matches the engine's start-order
-    sort.  Returns ``(ts, depth)`` where ``depth[J]`` is the number of ring
-    entries ahead of each enqueued row (its queue position at entry).
-
-    Ring-full safety valve: if a link's ring has no room (only possible when
-    ``queue_slots`` was shrunk below the job capacity), the transfer
-    activates immediately, bypassing the cap, and ``n_overflow`` counts it.
+    Each gets the next enqueue ticket; same-round enqueuers take them in
+    job-id order — they start at the same instant, and the id tiebreak
+    matches the engine's start-order sort.
     """
-    from .engine import _segment_exclusive_base
-
-    L, Q = ts.queue.shape[-2], ts.queue.shape[-1]
-    J = want.shape[-1]
-    idx = jnp.arange(J, dtype=jnp.int32)
+    L = ts.cap.shape[-1]
     lc = jnp.clip(link, 0, L - 1)
-    seg = jnp.where(want, lc, L)
-    order = jnp.argsort(seg, stable=True)
-    incl = _segment_exclusive_base(want[order].astype(jnp.int32), seg[order], L + 1)
-    rank = jnp.zeros((J,), jnp.int32).at[order].set(incl - want[order].astype(jnp.int32))
-    depth = ts.qlen[lc] + rank                   # entries ahead at enqueue time
-    room = want & (depth < Q)
-    slot = (ts.head[lc] + depth) % Q
-    # unique ticket per enqueue event: running counter + within-round rank
-    grank = jnp.cumsum(want.astype(jnp.int32)) - want.astype(jnp.int32)
-    tkt = ts.n_enq + grank
-    tgt = jnp.where(room, lc * Q + slot, L * Q)  # OOB rows dropped by the scatter
-    queue = ts.queue.reshape(L * Q).at[tgt].set(idx, mode="drop").reshape(L, Q)
-    tickets = ts.tickets.reshape(L * Q).at[tgt].set(tkt, mode="drop").reshape(L, Q)
-    ovf = want & ~room
+    w = want.astype(jnp.int32)
+    tkt = ts.n_enq + jnp.cumsum(w) - w
     return ts._replace(
-        queue=queue,
-        tickets=tickets,
-        qlen=ts.qlen + _link_count(room, lc, L),
-        active=ts.active + _link_count(ovf, lc, L),
-        stat=jnp.where(room, T_QUEUED, jnp.where(ovf, T_ACTIVE, ts.stat)),
+        stat=jnp.where(want, T_QUEUED, ts.stat),
         link=jnp.where(want, lc, ts.link),
         rem=jnp.where(want, nbytes, ts.rem),
         resid=jnp.where(want, resid, ts.resid),
@@ -195,42 +155,54 @@ def _enqueue(ts: TransferState, want, link, nbytes, resid, cache, clock):
         act_t=jnp.where(want, clock, ts.act_t),  # re-stamped on admission
         ticket=jnp.where(want, tkt, ts.ticket),
         cache=jnp.where(want, cache, ts.cache),
-        n_enq=ts.n_enq + want.sum().astype(jnp.int32),
-        n_overflow=ts.n_overflow + ovf.sum().astype(jnp.int32),
+        n_enq=ts.n_enq + w.sum(),
         bytes_enq=ts.bytes_enq + jnp.where(want, nbytes, 0.0).sum(),
-    ), depth
+    )
+
+
+def _queue_rank(ts: TransferState):
+    """``i32[J]``: each queued row's place in its link's FIFO (0 = head), the
+    number of queued rows on its link with an earlier ticket.
+
+    A stable sort by ticket, then by link (rows not queued last), puts each
+    link's queue in one run; a row's place is its distance from the run's
+    first position."""
+    from .engine import _lexsort_i32
+
+    L = ts.cap.shape[-1]
+    J = ts.stat.shape[-1]
+    seg = jnp.where(ts.stat == T_QUEUED, ts.link, L)
+    perm = _lexsort_i32((ts.ticket, seg))
+    seg_s = seg[perm]
+    pos = jnp.arange(J, dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), bool), seg_s[1:] != seg_s[:-1]])
+    run_start = jax.lax.cummax(jnp.where(first, pos, 0))
+    return jnp.zeros((J,), jnp.int32).at[perm].set(pos - run_start)
 
 
 def _admit(ts: TransferState, clock):
-    """Pop each link's FIFO into the free ``cap - active`` slots.
+    """Move the head of each link's queue into its free ``cap - active``
+    slots.  Returns ``(ts, rank)``, ``rank`` being ``_queue_rank`` before
+    admission (0 on rounds with nothing queued, when the sort is skipped)."""
+    from .engine import _ensemble_any
 
-    A ring entry is *live* iff the job it names is still T_QUEUED under the
-    same ticket; stale entries (cancelled by preemption, then possibly
-    re-enqueued under a new ticket) are tombstones and pop for free — even
-    at zero budget — so they can never wedge a queue.
-    """
-    L, Q = ts.queue.shape[-2], ts.queue.shape[-1]
+    L = ts.cap.shape[-1]
     J = ts.stat.shape[-1]
-    off = jnp.arange(Q, dtype=jnp.int32)[None, :]
-    pos = (ts.head[:, None] + off) % Q
-    ent = jnp.take_along_axis(ts.queue, pos, axis=-1)
-    tkt = jnp.take_along_axis(ts.tickets, pos, axis=-1)
-    in_q = off < ts.qlen[:, None]
-    ec = jnp.clip(ent, 0, J - 1)
-    live = in_q & (ent >= 0) & (ts.stat[ec] == T_QUEUED) & (ts.ticket[ec] == tkt)
-    vcum = jnp.cumsum(live.astype(jnp.int32), axis=-1)
-    budget = jnp.maximum(ts.cap - ts.active, 0)[:, None]
-    popped = in_q & (vcum <= budget)  # contiguous head prefix: tombstones ride along
-    admit = popped & live
-    ids = jnp.where(admit, ec, J).reshape(-1)
-    go = jnp.zeros((J + 1,), bool).at[ids].set(True)[:J]
-    n_pop = popped.sum(-1).astype(jnp.int32)
-    return ts._replace(
-        head=(ts.head + n_pop) % Q,
-        qlen=ts.qlen - n_pop,
-        active=ts.active + admit.sum(-1).astype(jnp.int32),
-        stat=jnp.where(go, T_ACTIVE, ts.stat),
-        act_t=jnp.where(go, clock, ts.act_t),
+    queued = ts.stat == T_QUEUED
+
+    def admit(ts):
+        rank = _queue_rank(ts)
+        lc = jnp.clip(ts.link, 0, L - 1)
+        go = queued & (rank < jnp.maximum(ts.cap - ts.active, 0)[lc])
+        return ts._replace(
+            active=ts.active + _link_count(go, lc, L),
+            stat=jnp.where(go, T_ACTIVE, ts.stat),
+            act_t=jnp.where(go, clock, ts.act_t),
+            n_waited=ts.n_waited + (go & (clock > ts.enq_t)).sum().astype(jnp.int32),
+        ), rank
+
+    return jax.lax.cond(
+        _ensemble_any(queued.any()), admit, lambda ts: (ts, jnp.zeros((J,), jnp.int32)), ts
     )
 
 
@@ -295,7 +267,7 @@ def _tr_on_completions(sub, ctx):
 
     # a preempted staging job (availability outage moved it out of RUNNING
     # in this same hook phase — availability runs first) abandons its
-    # transfer; its ring entry becomes a tombstone
+    # transfer, queued or active
     staging = jobs.state == RUNNING
     fin = act & (ts.t_done <= ctx.clock) & staging
     cancel = (ts.stat > T_IDLE) & ~staging
@@ -335,7 +307,7 @@ def _tr_on_completions(sub, ctx):
         bytes_done=ts.bytes_done + jnp.where(fin, jobs.xfer_bytes, 0.0).sum(),
         bytes_cancel=ts.bytes_cancel + jnp.where(cancel, jobs.xfer_bytes, 0.0).sum(),
     )
-    ts = _admit(ts, ctx.clock)
+    ts, _ = _admit(ts, ctx.clock)
     ctx.ext["transfers"] = _reprice(ts, bw_flat, ctx.clock)
     ctx.progressed = ctx.progressed | fin.any() | cancel.any()
 
@@ -355,15 +327,17 @@ def _tr_on_start(sub, ctx):
         # staging gate: inf service time keeps t_finish = inf, excluding the
         # job from the clock min-reduction until its transfer lands
         ctx.t_serv = jnp.where(xfer, INF, ctx.t_serv)
-        ts, depth = _enqueue(ts, xfer, sc["link"], sc["bytes"], sc["resid"], sc["cache"], ctx.clock)
-        ctx.jobs = ctx.jobs._replace(
-            xfer_qdepth=jnp.where(xfer, depth, ctx.jobs.xfer_qdepth),
-            xfer_wait=jnp.where(xfer, 0.0, ctx.jobs.xfer_wait),
-        )
+        ts = _enqueue(ts, xfer, sc["link"], sc["bytes"], sc["resid"], sc["cache"], ctx.clock)
     # newly enqueued flows activate now if their link has free slots —
     # required for liveness: an uncontended transfer must create its own
     # wake event this same round
-    ts = _admit(ts, ctx.clock)
+    ts, rank = _admit(ts, ctx.clock)
+    if sc is not None:
+        # a new row's place in its link's queue is the depth it saw at enqueue
+        ctx.jobs = ctx.jobs._replace(
+            xfer_qdepth=jnp.where(xfer, rank, ctx.jobs.xfer_qdepth),
+            xfer_wait=jnp.where(xfer, 0.0, ctx.jobs.xfer_wait),
+        )
     ctx.ext["transfers"] = _reprice(ts, dext.network.bw.reshape(L), ctx.clock)
 
 
@@ -393,18 +367,15 @@ def _tr_pad_jobs(sub, ts: TransferState, old_cap: int, new_cap: int):
         widths = [(0, 0)] * (x.ndim - 1) + [(0, n)]
         return jnp.pad(x, widths, constant_values=fills[name])
 
-    out = ts._replace(**{k: pad(k, getattr(ts, k)) for k in fills})
-    # default-sized rings (Q == job capacity) grow with it, keeping the
-    # no-overflow guarantee and a stackable shape across ragged lanes;
-    # explicit queue_slots are left alone (pre-run rings are empty, so
-    # widening never disturbs ring arithmetic)
-    if ts.queue.shape[-1] == old_cap:
-        widths = [(0, 0)] * (ts.queue.ndim - 1) + [(0, n)]
-        out = out._replace(
-            queue=jnp.pad(ts.queue, widths, constant_values=-1),
-            tickets=jnp.pad(ts.tickets, widths, constant_values=-1),
-        )
-    return out
+    return ts._replace(**{k: pad(k, getattr(ts, k)) for k in fills})
+
+
+def _tr_finalize(sub, ts: TransferState, jobs, sites, clock):
+    return ts, {
+        "xfer_enqueued": ts.n_enq,
+        "xfer_landed": ts.n_done,
+        "xfer_waited": ts.n_waited,
+    }
 
 
 def transfers_subsystem() -> "Subsystem":
@@ -423,4 +394,5 @@ def transfers_subsystem() -> "Subsystem":
         log_spec=_tr_log_spec,
         log_columns=_tr_log_columns,
         pad_jobs=_tr_pad_jobs,
+        finalize=_tr_finalize,
     )

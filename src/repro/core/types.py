@@ -51,7 +51,7 @@ class JobsState(NamedTuple):
     xfer_bytes: jax.Array  # f32[J] WAN bytes moved by the last stage-in (0 = cache hit)
     xfer_time: jax.Array  # f32[J] stage-in duration of the last attempt
     xfer_wait: jax.Array  # f32[J] transfer queue-wait of the last attempt (0 = never queued)
-    xfer_qdepth: jax.Array  # i32[J] link-queue depth seen at enqueue (-1 = never enqueued)
+    xfer_qdepth: jax.Array  # i32[J] queued transfers ahead on the link at enqueue (-1 = never enqueued)
     preempted: jax.Array  # i32[J] attempts cut short by site outages (DESIGN.md §5)
     wf_id: jax.Array      # i32[J] workflow the job belongs to, -1 = standalone
     n_parents: jax.Array  # i32[J] number of DAG parents (0 = root / standalone)
@@ -142,9 +142,17 @@ class SimResult(NamedTuple):
     replicas: object = None     # final ReplicaState (None without a DataPolicy)
     data_state: object = ()
     data_wide_rounds: object = None  # i32[] rounds whose stage-in ran on all J rows
+    xfer_enqueued: object = None  # i32[] WAN reads queued for transfer (None without transfers)
+    xfer_landed: object = None    # i32[] transfers landed
+    xfer_waited: object = None    # i32[] transfers admitted after waiting for a link slot
     avail: object = None        # final AvailabilityState (None without availability)
     wf: object = None           # final WorkflowState (None without a workflow DAG)
     ext: object = None          # {name: final state} for every attached subsystem
+
+
+# SimResult's subsystem counters (i32[], None where the subsystem is absent):
+# ``simulate`` and ``monitor.watch`` give each to a recorder as a gauge.
+RESULT_COUNTERS = ("data_wide_rounds", "xfer_enqueued", "xfer_landed", "xfer_waited")
 
 
 def make_jobs(

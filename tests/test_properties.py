@@ -32,7 +32,7 @@ from repro.core import (
     zipf_dataset_sizes,
 )
 from repro.core.events import transition_rows
-from repro.core.monitor import link_occupancy_timeline
+from repro.core.monitor import link_occupancy_timeline, transfer_queue_timeline
 
 POLICIES = ["random", "round_robin", "least_loaded", "shortest_wait", "panda_dispatch"]
 
@@ -283,8 +283,8 @@ _XFER_LOG_ROWS = 4096  # plenty: rounds ~ O(jobs * retries), far below this
 )
 def test_transfer_conservation_laws(n_jobs, seed, cap, fail_rate, with_avail):
     """Transfer-queue invariants (ISSUE 8): every enqueue is accounted as a
-    completion or a cancellation (in flows and in bytes), the overflow valve
-    never fires at default ring sizing, queues fully drain by termination,
+    completion or a cancellation (in flows and in bytes), no more transfers
+    waited for a slot than were enqueued, queues fully drain by termination,
     and per-link occupancy never exceeds the cap at any logged round."""
     res, jobs0, sites0, kw = build_scenario(
         n_jobs, seed, "least_loaded", fail_rate=fail_rate,
@@ -297,9 +297,9 @@ def test_transfer_conservation_laws(n_jobs, seed, cap, fail_rate, with_avail):
     n_enq = int(ts.n_enq)
     n_done = int(ts.n_done)
     n_cancel = int(ts.n_cancel)
-    # flow accounting: enqueues == completions + cancellations, no overflow
+    # flow accounting: enqueues == completions + cancellations
     assert n_enq == n_done + n_cancel
-    assert int(ts.n_overflow) == 0
+    assert 0 <= int(ts.n_waited) <= n_enq
     np.testing.assert_allclose(
         float(ts.bytes_enq), float(ts.bytes_done) + float(ts.bytes_cancel), rtol=1e-4
     )
@@ -309,7 +309,7 @@ def test_transfer_conservation_laws(n_jobs, seed, cap, fail_rate, with_avail):
     # queues drain: no transfer left queued or active, all slots released
     assert (np.asarray(ts.stat) == 0).all()
     assert (np.asarray(ts.active) == 0).all()
-    assert (np.asarray(ts.qlen) == 0).all()
+    assert (transfer_queue_timeline(res)[-1] == 0).all()  # the last round's queues
     # per-link occupancy respects the cap at every logged round; the log
     # ring did not wrap, so this covers the whole run
     assert int(np.asarray(res.log.cursor)) <= _XFER_LOG_ROWS
